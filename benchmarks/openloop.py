@@ -162,6 +162,9 @@ def main(argv=None) -> int:
     if args.sync:
         jax.config.update("jax_cpu_enable_async_dispatch", False)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import numpy as np  # noqa: F401  (transitively required anyway)
 
     from repro.configs import get_config

@@ -342,6 +342,9 @@ def main():
     if not args.async_dispatch:
         jax.config.update("jax_cpu_enable_async_dispatch", False)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from dataclasses import replace
 
     from repro.configs import get_config

@@ -47,13 +47,9 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from ..kernels.branch_matmul.ops import grouped_branch_matmul
 from .graph import Graph, Node, region_boundary_tensors
 from .plan import ExecutionPlan, fn_fingerprint, plan_signature
-
-try:  # grouped Pallas GEMM; gate batching off if pallas is unavailable
-    from ..kernels.branch_matmul.ops import grouped_branch_matmul
-except Exception:  # pragma: no cover - stripped-down installs
-    grouped_branch_matmul = None
 
 
 # --------------------------------------------------------------------------
@@ -209,8 +205,11 @@ def _apply_node(env: dict, node: Node) -> None:
 
 
 def _run_layer_traced(plan: ExecutionPlan, sl, env: dict,
-                      batch_map: "dict[tuple, frozenset]") -> None:
-    """Emit one scheduled layer into the current trace."""
+                      batch_map: "dict[tuple, frozenset]",
+                      platform: str) -> None:
+    """Emit one scheduled layer into the current trace; ``platform`` is
+    that of the device the layer runs on (it picks the grouped GEMM's
+    compiled or interpreted form)."""
     g = plan.graph
     for group in sl.parallel_groups:
         positions = batch_map.get(tuple(group), frozenset())
@@ -221,7 +220,8 @@ def _run_layer_traced(plan: ExecutionPlan, sl, env: dict,
                 if pos in positions:
                     xs = [env[n.inputs[0]] for n in nodes]
                     ws = [env[n.inputs[1]] for n in nodes]
-                    for n, o in zip(nodes, grouped_branch_matmul(xs, ws)):
+                    outs = grouped_branch_matmul(xs, ws, platform=platform)
+                    for n, o in zip(nodes, outs):
                         env[n.outputs[0]] = o
                 else:
                     for n in nodes:
@@ -237,7 +237,7 @@ def _run_layer_traced(plan: ExecutionPlan, sl, env: dict,
 
 def _batch_map(plan: ExecutionPlan,
                use_branch_kernel: bool) -> "dict[tuple, frozenset]":
-    if not use_branch_kernel or grouped_branch_matmul is None:
+    if not use_branch_kernel:
         return {}
     out = {}
     for sl in plan.schedule.layers:
@@ -249,7 +249,7 @@ def _batch_map(plan: ExecutionPlan,
 
 
 def _lower_region(plan: ExecutionPlan, sls: list,
-                  batch_map: "dict[tuple, frozenset]"):
+                  batch_map: "dict[tuple, frozenset]", platform: str):
     """(fn, in_ids, out_ids) executing the given scheduled layers as one
     traced region with graph-level boundary inference."""
     region = {nid for sl in sls for b in sl.all_branches()
@@ -259,7 +259,7 @@ def _lower_region(plan: ExecutionPlan, sls: list,
     def fn(*args):
         env = dict(zip(in_ids, args))
         for sl in sls:
-            _run_layer_traced(plan, sl, env, batch_map)
+            _run_layer_traced(plan, sl, env, batch_map, platform)
         return tuple(env[t] for t in out_ids)
 
     return fn, tuple(in_ids), tuple(out_ids)
@@ -311,14 +311,17 @@ def compile_schedule(plan: ExecutionPlan, *, whole_plan: bool = False,
                      donate: "bool | None" = None) -> CompiledSchedule:
     """Lower ``plan`` into fused callables, reusing cached compilations.
 
-    ``donate=None`` enables buffer donation exactly when the backend
-    supports it (CPU does not and would warn on every dispatch).
+    The callables run on the default device, whose platform decides the
+    grouped GEMM's form.  ``donate=None`` enables buffer donation exactly
+    when that device supports it (the CPU does not and would warn on
+    every dispatch).
     """
+    platform = jax.devices()[0].platform
     if donate is None:
-        donate = jax.default_backend() != "cpu"
-    use_branch_kernel = use_branch_kernel and grouped_branch_matmul is not None
+        donate = platform != "cpu"
     per_graph = _COMPILE_CACHE.setdefault(plan.graph, {})
-    key = (plan_signature(plan), whole_plan, use_branch_kernel, donate)
+    key = (plan_signature(plan), whole_plan, use_branch_kernel, donate,
+           platform)
     cached = per_graph.get(key)
     if cached is not None:
         return cached
@@ -336,11 +339,11 @@ def compile_schedule(plan: ExecutionPlan, *, whole_plan: bool = False,
     whole: "CompiledLayer | None" = None
     if whole_plan:
         fn, in_ids, out_ids = _lower_region(plan, list(sched.layers),
-                                            batch_map)
+                                            batch_map, platform)
         whole = CompiledLayer(-1, jax.jit(fn), in_ids, out_ids,
                               sched.max_width(), ())
     else:
-        lowered = [_lower_region(plan, [sl], batch_map)
+        lowered = [_lower_region(plan, [sl], batch_map, platform)
                    for sl in sched.layers]
         donatable = _donate_argnums(plan, [l[1] for l in lowered])
         for sl, (fn, in_ids, out_ids), nums in zip(sched.layers, lowered,
@@ -357,7 +360,8 @@ def compile_schedule(plan: ExecutionPlan, *, whole_plan: bool = False,
 
 
 def compile_hetero_schedule(plan: ExecutionPlan, *,
-                            use_branch_kernel: bool = True
+                            use_branch_kernel: bool = True,
+                            device_map: "dict | None" = None
                             ) -> CompiledHeteroSchedule:
     """Lower a *placed* plan into one fused callable per (layer, device).
 
@@ -373,16 +377,22 @@ def compile_hetero_schedule(plan: ExecutionPlan, *,
     §3.1 layer property), so a layer's segments may dispatch concurrently
     on their devices; the runtime orders them deterministically.  Cached
     like :func:`compile_schedule`; the plan signature already covers the
-    placement.
+    placement.  ``device_map`` (logical device -> ``jax.Device``,
+    default :func:`~repro.hetero.placement.resolve_devices`) gives each
+    segment the platform its grouped GEMMs are built for.
     """
     from .scheduler import ScheduledLayer
     placement = plan.placement
     if placement is None:
         raise ValueError("plan has no placement — heterogenize() it first "
                          "(repro.hetero)")
-    use_branch_kernel = use_branch_kernel and grouped_branch_matmul is not None
+    if device_map is None:
+        from ..hetero.placement import resolve_devices
+        device_map = resolve_devices(placement)
+    platforms = {dev: d.platform for dev, d in device_map.items()}
     per_graph = _COMPILE_CACHE.setdefault(plan.graph, {})
-    key = ("hetero", plan_signature(plan), use_branch_kernel)
+    key = ("hetero", plan_signature(plan), use_branch_kernel,
+           tuple(sorted(platforms.items())))
     cached = per_graph.get(key)
     if cached is not None:
         return cached
@@ -419,7 +429,8 @@ def compile_hetero_schedule(plan: ExecutionPlan, *,
 
         for dev in sorted(per_dev):
             psl = per_dev[dev]
-            fn, in_ids, out_ids = _lower_region(plan, [psl], batch_map)
+            fn, in_ids, out_ids = _lower_region(plan, [psl], batch_map,
+                                                platforms[dev])
             segments.append(CompiledSegment(
                 sl.layer_index, dev, jax.jit(fn), in_ids, out_ids,
                 psl.width(), tuple(psl.all_branches())))

@@ -17,6 +17,8 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass, field
 
+import jax
+
 DEFAULT_MARGIN = 0.4      # paper: 30-50 % safety margin
 DEFAULT_MAX_PARALLEL = 6  # paper §4.3: max thread count 6
 
@@ -36,9 +38,12 @@ def query_available_memory() -> int:
     """Available memory in bytes for the §3.3 budget.
 
     Resolution order: the ``PARALLAX_MEM_BUDGET`` env var (explicit
-    operator override — supports K/M/G/T suffixes, e.g. ``4G``), then
-    /proc/meminfo MemAvailable, then an 8 GiB fallback for platforms
-    exposing neither.
+    operator override — supports K/M/G/T suffixes, e.g. ``4G``), then the
+    free memory of JAX's first device — host ``MemAvailable`` from
+    /proc/meminfo where that is the CPU, and ``bytes_limit -
+    bytes_in_use`` from its ``memory_stats()`` anywhere else, so a budget
+    taken after the weights are placed excludes them.
+    A device that reports neither raises: no size is guessed.
     """
     env = os.environ.get(MEM_BUDGET_ENV)
     if env:
@@ -52,14 +57,19 @@ def query_available_memory() -> int:
                 f"{MEM_BUDGET_ENV}={env!r} must be positive — a zero or "
                 f"negative budget silently serializes every schedule")
         return n
-    try:
+    device = jax.devices()[0]
+    if device.platform == "cpu":
         with open("/proc/meminfo") as f:
             for line in f:
                 if line.startswith("MemAvailable:"):
                     return int(line.split()[1]) * 1024
-    except OSError:  # pragma: no cover - non-Linux fallback
-        pass
-    return 8 << 30
+        raise RuntimeError(f"/proc/meminfo has no MemAvailable; set "
+                           f"{MEM_BUDGET_ENV}")
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        raise RuntimeError(f"{device} reports no memory limit; set "
+                           f"{MEM_BUDGET_ENV}")
+    return stats["bytes_limit"] - stats.get("bytes_in_use", 0)
 
 
 def memory_budget(available: "int | None" = None,

@@ -50,9 +50,10 @@ class HeteroExecutor:
                              "repro.hetero.heterogenize(plan) first")
         self.plan = plan
         self.profile = profile
-        self.compiled = compile_hetero_schedule(
-            plan, use_branch_kernel=use_branch_kernel)
         self.device_map = resolve_devices(plan.placement, devices)
+        self.compiled = compile_hetero_schedule(
+            plan, use_branch_kernel=use_branch_kernel,
+            device_map=self.device_map)
         transfers = plan.attrs.get("transfers")
         if not isinstance(transfers, TransferPlan):
             transfers = plan_transfers(plan, plan.placement)

@@ -23,8 +23,9 @@ branch-level parallelism becomes device-level parallelism when more than
 one accelerator exists.
 
 Logical devices are resolved to physical ``jax.Device``s by
-:func:`resolve_devices`: physical device 0 is the host, devices 1..D-1 are
-accelerators.  Multi-device simulation in CI uses
+:func:`resolve_devices`: the host is CPU device 0, and the accelerators
+are the default backend's other devices (all of them on a TPU machine;
+devices 1..D-1 on a CPU-only one).  Multi-device simulation in CI uses
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``; with a single
 physical device every logical device aliases it, so placement (and its
 byte accounting) still runs everywhere.
@@ -87,13 +88,16 @@ class PlacementPlan:
 
 
 def _default_host():
-    """The physical device hosting fallbacks: the CPU platform when one is
-    registered (real accelerator machines — jax.devices() is all GPUs/TPUs
-    there and must stay the accel pool), else default device 0."""
+    """The physical device hosting fallbacks: CPU device 0.  On an
+    accelerator machine jax.devices() is all GPUs/TPUs and stays the
+    accel pool, so the CPU platform must be registered; without it no
+    accelerator is quietly made to stand in for the host."""
     try:
         return jax.devices("cpu")[0]
-    except RuntimeError:  # pragma: no cover - CPU platform absent
-        return jax.devices()[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "the hetero runtime needs a CPU device as its fallback host; "
+            "add 'cpu' to JAX_PLATFORMS") from e
 
 
 def logical_accel_count(devices=None) -> int:

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(3)
@@ -70,7 +68,7 @@ def branch_matmul(x, w, *, block_m: int = 128, block_n: int = 128,
                                lambda g, i, j, k: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

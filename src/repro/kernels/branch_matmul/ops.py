@@ -33,7 +33,7 @@ def _pad_to(a, m, axis):
     return jnp.pad(a, widths)
 
 
-def parallel_branches(xs, ws, interpret=True, block_m=8, block_n=128,
+def parallel_branches(xs, ws, interpret=False, block_m=8, block_n=128,
                       block_k=128):
     """Fuse a list of per-branch (x_i (M_i, K), w_i (K, N)) matmuls.
 
@@ -55,15 +55,16 @@ def parallel_branches(xs, ws, interpret=True, block_m=8, block_n=128,
     return [out[i, :xs[i].shape[0], :N] for i in range(len(xs))]
 
 
-def grouped_branch_matmul(xs, ws, interpret=None, **blocks):
-    """Backend-aware entry point for the schedule compiler (core/compile.py).
+def grouped_branch_matmul(xs, ws, *, platform: str, **blocks):
+    """Placement-aware entry point for the schedule compiler
+    (core/compile.py).
 
-    Identical semantics to :func:`parallel_branches`; picks the compiled
-    Pallas kernel on TPU and interpreter mode elsewhere unless overridden.
+    Identical semantics to :func:`parallel_branches`.  ``platform`` is
+    that of the device the group was placed on: the compiled Mosaic
+    kernel runs on a TPU, the interpreter everywhere else (a group
+    placed on the CPU host of a TPU machine included).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return parallel_branches(xs, ws, interpret=interpret, **blocks)
+    return parallel_branches(xs, ws, interpret=platform != "tpu", **blocks)
 
 
 __all__ = ["branch_matmul_op", "branch_matmul_ref", "grouped_branch_matmul",

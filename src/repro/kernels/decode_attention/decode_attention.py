@@ -26,7 +26,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -114,7 +113,7 @@ def decode_attention(q, k, v, pos, cache_len, *, window: int = 0,
                           block_k=block_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, q4, k, v, pos)

@@ -20,7 +20,7 @@ def flash_attention_op(q, k, v, causal=True, window=0, block_q=128,
                            interpret=interpret)
 
 
-def attend_bshd(q, k, v, causal=True, window=0, interpret=True,
+def attend_bshd(q, k, v, causal=True, window=0, interpret=False,
                 block_q=128, block_k=128):
     """Adapter for the models' (B, S, H, D) layout."""
     qt = q.transpose(0, 2, 1, 3)

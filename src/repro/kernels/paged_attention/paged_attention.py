@@ -37,7 +37,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -135,7 +134,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, cache_len, *,
                           n_blk=bpr, block_size=bs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, cache_len, q4, k_pool, v_pool)
@@ -207,7 +206,7 @@ def paged_append(k_pool, v_pool, k_new, v_new, block_tables, lens,
         # in-place: pools are donated to the outputs (operand indices
         # count the scalar-prefetch args)
         input_output_aliases={3: 0, 4: 1},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(block_tables, lens, n_valid, k_pool, v_pool, k_new, v_new)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams
-
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, state_ref, *,
             chunk: int):
@@ -87,7 +85,7 @@ def ssd_scan(x, dt, Bm, Cm, a, *, interpret: bool = False):
                                lambda b, h, c: (b, h, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, C, L, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bm, Cm, a)

@@ -2,8 +2,16 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch stablelm-3b \
         --requests 8 --max-new 16 [--engine round|continuous] \
+        [--reduced | --full] \
         [--arrival-rate R | --trace-file PATH] [--deadline-s D] \
         [--megastep N] [--host-pool 512M] [--fault-seed S] ...
+
+``--reduced`` (the default, for the CPU) serves the architecture cut to
+<= 2 layers and ``d_model`` <= 256 in float32; ``--full`` serves it at
+its published width in its own dtype (bfloat16), which needs an
+accelerator.  Weights are random, drawn from ``--seed``.  The KV budget
+(``--hbm-budget``) defaults to the device's free memory once the
+weights are placed.
 
 Every engine knob flag (``--hbm-budget``, ``--max-batch``,
 ``--megastep``, ``--host-pool``, ``--fault-seed``, ``--max-queue``,
@@ -43,6 +51,7 @@ import time
 from dataclasses import replace
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config
@@ -54,10 +63,34 @@ from repro.runtime.faults import FaultPlane
 from repro.runtime.telemetry import Telemetry
 from repro.runtime.workload import OpenLoopWorkload, percentile, \
     run_open_loop
+from repro.utils.compile_cache import enable_compile_cache
+
+
+def model_config(arch: str, full: bool = False):
+    """The architecture's config at its published width (``full``) or
+    cut to the CPU-sized ``reduced()`` variant."""
+    cfg = get_config(arch)
+    return cfg if full else cfg.reduced()
+
+
+def init_params(api, seed: int):
+    """Random weights from ``seed`` in the config's dtype.  Init and cast
+    run as one jit, so no float32 copy of the whole tree is ever held on
+    the device (stablelm-3b: 11.2 GB in float32, 5.6 GB in bfloat16)."""
+    dtype = jnp.dtype(api.cfg.dtype)
+
+    def init(key):
+        return jax.tree.map(
+            lambda a: a.astype(dtype)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a,
+            api.init(key))
+
+    return jax.jit(init)(jax.random.key(seed))
 
 
 def serve(arch: str, n_requests: int = 8, max_new: int = 16,
-          budget_mb: int = 256, prompt_len: int = 12, seed: int = 0,
+          budget_mb: "int | None" = None, prompt_len: int = 12,
+          seed: int = 0,
           max_batch: int = 4, engine_mode: str = "round",
           paged: bool = True, megastep: "int | None" = None,
           fault_seed: "int | None" = None,
@@ -67,16 +100,28 @@ def serve(arch: str, n_requests: int = 8, max_new: int = 16,
           host_pool: "int | None" = None,
           config: "EngineConfig | None" = None,
           arrival_rate: "float | None" = None,
-          trace_file: "str | None" = None):
-    cfg = get_config(arch).reduced()
+          trace_file: "str | None" = None,
+          full: bool = False, params=None,
+          requests: "list[Request] | None" = None):
+    """Serve ``n_requests`` random prompts (or the given ``requests``)
+    and return their completions by id.
+
+    ``params`` are weights already built by :func:`init_params` for the
+    same config (drawn from ``seed`` when omitted).  A ``config`` built
+    before the weights are on the device counts their bytes as free KV
+    budget: build it after them, or leave it to this function.
+    """
+    cfg = model_config(arch, full)
     api = build_model(cfg)
-    params = api.init(jax.random.key(seed))
+    if params is None:
+        params = init_params(api, seed)
     tele = Telemetry(trace=trace_path is not None)
     if config is None:
         # legacy keyword surface: a kwarg left at None is unset and
         # falls through EngineConfig's env-then-default resolution
         config = EngineConfig(
-            hbm_budget=budget_mb << 20, max_batch=max_batch,
+            hbm_budget=None if budget_mb is None else budget_mb << 20,
+            max_batch=max_batch,
             paged=paged,
             max_context=(prompt_len + max_new
                          if engine_mode == "continuous" else None),
@@ -111,7 +156,16 @@ def serve(arch: str, n_requests: int = 8, max_new: int = 16,
             config = replace(config, max_context=need)
         request_ids = [a.request.id for a in workload]
     else:
-        request_ids = list(range(n_requests))
+        if requests is None:
+            rng = np.random.default_rng(seed)
+            requests = [Request(
+                id=i, prompt=rng.integers(
+                    0, cfg.vocab_size,
+                    int(rng.integers(4, prompt_len + 1))).astype(np.int32),
+                max_new_tokens=max_new, deadline_s=deadline_s)
+                for i in range(n_requests)]
+        n_requests = len(requests)
+        request_ids = [r.id for r in requests]
 
     faults = None
     if engine_mode == "continuous":
@@ -135,13 +189,8 @@ def serve(arch: str, n_requests: int = 8, max_new: int = 16,
         done, wall = res.completions, res.wall_s
         n_requests = len(workload)
     else:
-        rng = np.random.default_rng(seed)
-        for i in range(n_requests):
-            plen = int(rng.integers(4, prompt_len + 1))
-            engine.submit(Request(
-                id=i, prompt=rng.integers(
-                    0, cfg.vocab_size, plen).astype(np.int32),
-                max_new_tokens=max_new, deadline_s=deadline_s))
+        for req in requests:
+            engine.submit(req)
         t0 = time.time()
         done = engine.run()
         wall = time.time() - t0
@@ -176,6 +225,7 @@ def serve(arch: str, n_requests: int = 8, max_new: int = 16,
               f"({engine.megastep_steps} fused iters, "
               f"N={engine.megastep_n}), "
               f"preemptions {engine.preemptions}")
+        print(f"stepper traces {engine.stepper.trace_stats()}")
         if engine.spill_enabled:
             print(f"host tier: {engine.spills} spills / "
                   f"{engine.restores} restores, "
@@ -217,6 +267,11 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--engine", choices=("round", "continuous"),
                     default="round")
+    ap.add_argument("--reduced", action="store_true", default=True,
+                    help="serve the CPU-sized cut (the default)")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="serve the published width in the config's "
+                         "dtype (needs an accelerator)")
     ap.add_argument("--arrival-rate", type=float, default=None,
                     metavar="RPS",
                     help="open loop: Poisson arrivals at this req/s "
@@ -234,6 +289,12 @@ def main():
                          "and dispatch counts stay bit-identical")
     EngineConfig.add_cli_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
+    full = not args.reduced
+    # weights first: the config's default KV budget is the device memory
+    # still free once they are placed
+    params = init_params(build_model(model_config(args.arch, full)),
+                         args.seed)
     overrides = {}
     if args.max_context is None:
         # closed-loop default: prompt + generation exactly fit; the
@@ -246,7 +307,8 @@ def main():
           prompt_len=args.prompt_len, seed=args.seed,
           engine_mode=args.engine, deadline_s=args.deadline_s,
           trace_path=args.trace, config=config,
-          arrival_rate=args.arrival_rate, trace_file=args.trace_file)
+          arrival_rate=args.arrival_rate, trace_file=args.trace_file,
+          full=full, params=params)
 
 
 if __name__ == "__main__":

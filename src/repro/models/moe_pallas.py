@@ -22,7 +22,7 @@ from .mlp import mlp
 
 
 def moe_branch_matmul(params, cfg, x, *, capacity_factor: float = 2.0,
-                      interpret: bool = True, block_m: int = 8,
+                      interpret: bool = False, block_m: int = 8,
                       block_n: int = 128, block_k: int = 128):
     """x: (T, d) -> (y (T, d), aux).  Experts as branch-batched GEMMs."""
     m = cfg.moe

@@ -18,12 +18,7 @@ MODEL_AXIS = "model"
 
 
 def active_axes():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-    else:  # older jax: the thread-local physical mesh set by `with Mesh(...)`
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return ()
     return tuple(mesh.axis_names)

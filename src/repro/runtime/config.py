@@ -97,7 +97,8 @@ class EngineConfig:
     hbm_budget: int = _knob(
         None, env=MEM_BUDGET_ENV, parse=_parse_bytes, unit="bytes",
         help="device KV budget in bytes before the safety margin "
-             "(K/M/G/T suffixes ok); default probes /proc/meminfo")
+             "(K/M/G/T suffixes ok); default: the device's free memory "
+             "when the config is built (MemAvailable on the CPU)")
     margin: float = _knob(
         0.4, parse=float,
         help="fraction of hbm_budget held back from the KV pool")
@@ -176,7 +177,8 @@ class EngineConfig:
                 # CLI/str passthrough: "512M" budgets, "none" seeds, ...
                 value = meta["parse"](value)
             object.__setattr__(self, f.name, value)
-        # hbm_budget's default is machine-probed, not a literal
+        # hbm_budget's default is the device's free memory at this
+        # moment: build the config after the weights are placed
         if self.hbm_budget is None:
             object.__setattr__(self, "hbm_budget", query_available_memory())
         self._validate()

@@ -86,7 +86,30 @@ def test_memory_budget_env_override(monkeypatch):
         with pytest.raises(ValueError, match="positive"):
             query_available_memory()
     monkeypatch.delenv("PARALLAX_MEM_BUDGET")
-    assert query_available_memory() > 0    # /proc/meminfo (or fallback)
+    assert query_available_memory() > 0    # the CPU's MemAvailable
+
+
+def test_memory_query_reads_accelerator_memory_stats(monkeypatch):
+    """Off the CPU the budget is the device's free HBM, and a device
+    that reports no limit raises instead of a guessed size."""
+    import jax
+
+    class FakeChip:
+        platform = "tpu"
+
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    monkeypatch.delenv("PARALLAX_MEM_BUDGET", raising=False)
+    chip = FakeChip({"bytes_limit": 16 << 30, "bytes_in_use": 6 << 30})
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    assert query_available_memory() == 10 << 30
+    chip.stats = None
+    with pytest.raises(RuntimeError, match="PARALLAX_MEM_BUDGET"):
+        query_available_memory()
 
 
 def test_schedule_layers_extra_mems_defer():
