@@ -321,12 +321,13 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
     q, k_new, v_new = qkv_project(params, cfg, x, positions, positions3)
 
     slot = cache_len % slots
-    k = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-    pos = jax.lax.dynamic_update_slice_in_dim(
-        cache["pos"], cache_len[None], slot, axis=0)
+    with jax.named_scope("kv_write"):
+        k = jax.lax.dynamic_update_slice_in_dim(
+            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
+        v = jax.lax.dynamic_update_slice_in_dim(
+            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
+        pos = jax.lax.dynamic_update_slice_in_dim(
+            cache["pos"], cache_len[None], slot, axis=0)
 
     valid = (pos >= 0) & (pos <= cache_len)
     w = window or cfg.sliding_window
@@ -357,13 +358,14 @@ def _decode_step_attention_vec(params, cfg, x, cache, cache_len,
     q, k_new, v_new = qkv_project(params, cfg, x, positions, positions3)
 
     t = jnp.arange(slots, dtype=jnp.int32)[None, :]       # (1, T)
-    write = t == positions                                # (B, T)
-    if active is not None:
-        write &= active[:, None]
-    k = jnp.where(write[:, :, None, None],
-                  k_new.astype(cache["k"].dtype), cache["k"])
-    v = jnp.where(write[:, :, None, None],
-                  v_new.astype(cache["v"].dtype), cache["v"])
+    with jax.named_scope("kv_write"):
+        write = t == positions                            # (B, T)
+        if active is not None:
+            write &= active[:, None]
+        k = jnp.where(write[:, :, None, None],
+                      k_new.astype(cache["k"].dtype), cache["k"])
+        v = jnp.where(write[:, :, None, None],
+                      v_new.astype(cache["v"].dtype), cache["v"])
 
     valid = t <= positions
     w = window or cfg.sliding_window
@@ -407,17 +409,21 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
     q, k_new, v_new = qkv_project(params, cfg, x, positions, positions3)
 
     # scatter the new token into its physical block
-    lblk = jnp.clip(cache_len // bs, 0, bps - 1)          # logical block
-    bids = jnp.take_along_axis(block_tables, lblk[:, None], 1)[:, 0]
-    offs = cache_len % bs
-    if active is not None:
-        bids = jnp.where(active, bids, scratch)
-    pool_k = pool_k.at[bids, offs].set(k_new[:, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[bids, offs].set(v_new[:, 0].astype(pool_v.dtype))
+    with jax.named_scope("kv_write"):
+        lblk = jnp.clip(cache_len // bs, 0, bps - 1)      # logical block
+        bids = jnp.take_along_axis(block_tables, lblk[:, None], 1)[:, 0]
+        offs = cache_len % bs
+        if active is not None:
+            bids = jnp.where(active, bids, scratch)
+        pool_k = pool_k.at[bids, offs].set(
+            k_new[:, 0].astype(pool_k.dtype))
+        pool_v = pool_v.at[bids, offs].set(
+            v_new[:, 0].astype(pool_v.dtype))
 
     # gather the row's cache view through its block table
-    k = pool_k[block_tables].reshape(B, T, *pool_k.shape[2:])
-    v = pool_v[block_tables].reshape(B, T, *pool_v.shape[2:])
+    with jax.named_scope("kv_gather"):
+        k = pool_k[block_tables].reshape(B, T, *pool_k.shape[2:])
+        v = pool_v[block_tables].reshape(B, T, *pool_v.shape[2:])
 
     t = jnp.arange(T, dtype=jnp.int32)[None, :]           # (1, T)
     valid = t <= positions

@@ -73,22 +73,26 @@ def init_block(key, cfg, kind):
 
 
 def _channel_mix(params, cfg, x, kind, moe_impl, mesh):
+    """The block's channel mix and its residual add, named ``norm`` and
+    ``mlp`` (dense or MoE) in the program's name stack."""
     channel = kind[1]
     if channel == "none":
         return x, 0.0
     norm = make_norm(cfg.norm_type)
-    h = norm(params["norm2"], x)
-    if channel == "dense":
-        return x + mlp(params["mlp"], h, cfg.act), 0.0
-    B, S, d = h.shape
-    flat = h.reshape(B * S, d)
-    if moe_impl == "dense":
-        y, aux = moe_dense(params["moe"], cfg, flat)
-    elif moe_impl == "ep" and mesh is not None:
-        y, aux = moe_ep(params["moe"], cfg, flat, mesh)
-    else:
-        y, aux = moe_ragged(params["moe"], cfg, flat)
-    return x + y.reshape(B, S, d), aux
+    with jax.named_scope("norm"):
+        h = norm(params["norm2"], x)
+    with jax.named_scope("mlp"):
+        if channel == "dense":
+            return x + mlp(params["mlp"], h, cfg.act), 0.0
+        B, S, d = h.shape
+        flat = h.reshape(B * S, d)
+        if moe_impl == "dense":
+            y, aux = moe_dense(params["moe"], cfg, flat)
+        elif moe_impl == "ep" and mesh is not None:
+            y, aux = moe_ep(params["moe"], cfg, flat, mesh)
+        else:
+            y, aux = moe_ragged(params["moe"], cfg, flat)
+        return x + y.reshape(B, S, d), aux
 
 
 def apply_block(params, cfg, x, kind, positions=None, positions3=None,
@@ -96,13 +100,15 @@ def apply_block(params, cfg, x, kind, positions=None, positions3=None,
     """Full-sequence (train / prefill) block.  x: (B, S, d)."""
     mixer, _ = kind
     norm = make_norm(cfg.norm_type)
-    h = norm(params["norm1"], x)
+    with jax.named_scope("norm"):
+        h = norm(params["norm1"], x)
     if mixer == "attn":
-        y = self_attention(params["attn"], cfg, h, positions, positions3,
-                           causal=True, window=window)
+        with jax.named_scope("attention"):
+            x = x + self_attention(params["attn"], cfg, h, positions,
+                                   positions3, causal=True, window=window)
     else:
-        y = mamba_block(params["mamba"], cfg, h)
-    x = x + y
+        with jax.named_scope("ssm"):
+            x = x + mamba_block(params["mamba"], cfg, h)
     x = shard_batch_seq(x)
     x, aux = _channel_mix(params, cfg, x, kind, moe_impl, mesh)
     return shard_batch_seq(x), aux
@@ -146,25 +152,36 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
     mid-megastep freezes BOTH its attention KV writes (masked inside
     ``decode_step_attention``) and its SSM/conv state (the
     ``jnp.where`` below).
+
+    Each part and its residual add runs under a ``jax.named_scope``
+    (``norm``, ``attention`` or ``ssm``, then ``norm`` and ``mlp``), so
+    every operation of the served step names the part it belongs to in
+    its op metadata.
     """
     mixer, _ = kind
     norm = make_norm(cfg.norm_type)
-    h = norm(params["norm1"], x)
+    with jax.named_scope("norm"):
+        h = norm(params["norm1"], x)
     if mixer == "attn":
-        y, cache = decode_step_attention(params["attn"], cfg, h, cache,
-                                         cache_len, positions3,
-                                         active=active,
-                                         block_tables=block_tables)
+        with jax.named_scope("attention"):
+            y, cache = decode_step_attention(params["attn"], cfg, h, cache,
+                                             cache_len, positions3,
+                                             active=active,
+                                             block_tables=block_tables)
+            x = x + y
     else:
-        y, new_cache = mamba_decode_step(params["mamba"], cfg, h, cache)
-        if active is not None:
-            cache = jax.tree.map(
-                lambda n, o: jnp.where(
-                    active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
-                new_cache, cache)
-        else:
-            cache = new_cache
-    x = x + y
+        with jax.named_scope("ssm"):
+            y, new_cache = mamba_decode_step(params["mamba"], cfg, h,
+                                             cache)
+            if active is not None:
+                cache = jax.tree.map(
+                    lambda n, o: jnp.where(
+                        active.reshape((-1,) + (1,) * (n.ndim - 1)), n,
+                        o),
+                    new_cache, cache)
+            else:
+                cache = new_cache
+            x = x + y
     x = shard_decode(x)
     x, _aux = _channel_mix(params, cfg, x, kind, moe_impl, mesh)
     return shard_decode(x), cache

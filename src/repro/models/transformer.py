@@ -184,9 +184,16 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     ``block_tables`` (B, blocks_per_seq) must be passed when ``caches``
     were built by :func:`init_paged_caches` (one table routes every
     layer's pool).
+
+    The parts run under ``jax.named_scope``s (``embed``, each block's
+    in ``decode_block``, ``norm``, ``lm_head``); the layer scan itself
+    is left outside every scope, so its per-layer slices and write-backs
+    of the stacked weights and caches, and the copies the compiler adds
+    for the carried pool, carry no model scope.
     """
     pattern, prefix_len, period, n_rep = structure(cfg)
-    x = params["embed"].astype(cfg.dtype)[tokens]      # (B, 1, d)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]  # (B, 1, d)
 
     new_prefix = []
     for i in range(prefix_len):
@@ -217,6 +224,8 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
         new_period = list(new_period)
 
     norm = make_norm(cfg.norm_type)
-    hidden = norm(params["final_norm"], x)
-    logits = logits_last_token(params, cfg, hidden)
+    with jax.named_scope("norm"):
+        hidden = norm(params["final_norm"], x)
+    with jax.named_scope("lm_head"):
+        logits = logits_last_token(params, cfg, hidden)
     return logits, {"prefix": new_prefix, "period": new_period}

@@ -284,8 +284,6 @@ class ServingEngine:
         self._m_dispatches = m.counter("engine.dispatches")
         self._m_submitted = m.counter("engine.requests_submitted")
         self._m_resolved = m.counter("engine.requests_resolved")
-        self._h_prompt = m.histogram("engine.prompt_len")
-        self._g_queue = m.gauge("engine.queue_depth")
 
     def submit(self, req: Request) -> bool:
         _validate_request(req, self.max_context)
@@ -293,18 +291,12 @@ class ServingEngine:
                 or req.id in self.completed:
             raise ValueError(f"duplicate request id {req.id}")
         self._m_submitted.inc()
-        self._h_prompt.observe(len(req.prompt))
         self._rec.point("submit", request_id=req.id,
                         prompt_len=len(req.prompt),
                         max_new=req.max_new_tokens)
         self._submit_t[req.id] = time.perf_counter()
         self.queue.append(req)
-        self._g_queue.set(len(self.queue))
         return True
-
-    @property
-    def dispatch_count(self) -> int:
-        return self._m_dispatches.value
 
     @property
     def dispatches(self) -> int:
@@ -370,16 +362,16 @@ class ServingEngine:
         for t in range(0, int(plens.max()), C):
             n_valid = np.clip(plens - t, 0, C)
             self._m_dispatches.inc()
-            t_d = rec.now()
-            caches, _, first, _ = self.stepper.prefill_chunk(
-                self.params, caches, toks[:, t:t + C], lens, n_valid)
-            done_here = (t < plens) & (plens <= t + C)
-            if done_here.any():
-                first_host = np.asarray(first)
-                first_tok[done_here] = first_host[done_here]
-            lens += n_valid
-            rec.span("prefill_chunk", t_d, rows=int((n_valid > 0).sum()),
-                     tokens=int(n_valid.sum()))
+            with rec.region("prefill_chunk",
+                            rows=int((n_valid > 0).sum()),
+                            tokens=int(n_valid.sum())):
+                caches, _, first, _ = self.stepper.prefill_chunk(
+                    self.params, caches, toks[:, t:t + C], lens, n_valid)
+                done_here = (t < plens) & (plens <= t + C)
+                if done_here.any():
+                    first_host = np.asarray(first)
+                    first_tok[done_here] = first_host[done_here]
+                lens += n_valid
         prefill_s = time.perf_counter() - t0
         t_first = time.perf_counter()
         ttft_s = t_first - t_run0
@@ -411,14 +403,13 @@ class ServingEngine:
         while (count < max_new).any():
             active = count < max_new
             self._m_dispatches.inc()
-            t_d = rec.now()
             # the round baseline ignores the watchdog flag: it exists to
             # measure the continuous engine against, and its semantics
             # must not drift with the hardening work
-            last_dev, _, caches = self.stepper.decode(
-                self.params, caches, last, lens, active)
-            last = np.asarray(last_dev)
-            rec.span("decode", t_d, rows=int(active.sum()))
+            with rec.region("decode", rows=int(active.sum())):
+                last_dev, _, caches = self.stepper.decode(
+                    self.params, caches, last, lens, active)
+                last = np.asarray(last_dev)
             lens += active
             count += active
             for i, r in enumerate(batch_reqs):
@@ -464,7 +455,6 @@ class ServingEngine:
             raise MemoryError(
                 f"no queued request fits: smallest peak {smallest} "
                 f"bytes, headroom {self.kv.budget - self.kv.in_use}")
-        self._g_queue.set(len(self.queue))
         t_admit = time.perf_counter()
         for i, r in enumerate(batch_reqs):
             self.kv.admit(r.id, r.context_len())
@@ -497,7 +487,6 @@ class ServingEngine:
             self.completed[r.id] = comp
             self._drainable.append(comp)
         self.queue.clear()
-        self._g_queue.set(0)
         return self.completed
 
 
@@ -778,10 +767,6 @@ class ContinuousEngine:
         self._m_stalls = m.counter("engine.stalls")
         self._m_submitted = m.counter("engine.requests_submitted")
         self._m_resolved = m.counter("engine.requests_resolved")
-        self._h_prompt = m.histogram("engine.prompt_len")
-        self._h_generated = m.histogram("engine.generated_tokens")
-        self._h_megastep_len = m.histogram("engine.megastep_len")
-        self._g_queue = m.gauge("engine.queue_depth")
         self._deadlines_armed = False
         # decode megastep: N fused iterations per dispatch (1 = the
         # per-iteration path; env PARALLAX_MEGASTEP via EngineConfig)
@@ -809,7 +794,6 @@ class ContinuousEngine:
             # would admit twice against one charged cost
             raise ValueError(f"duplicate request id {req.id}")
         self._m_submitted.inc()
-        self._h_prompt.observe(len(req.prompt))
         self._rec.point("submit", request_id=req.id,
                         prompt_len=len(req.prompt),
                         max_new=req.max_new_tokens)
@@ -827,7 +811,6 @@ class ContinuousEngine:
         if req.deadline_s is not None:
             self._deadlines_armed = True
         self.waiting.append(_Seq(req, submit_t=time.perf_counter()))
-        self._g_queue.set(len(self.waiting))
         return True
 
     def cancel(self, req_id: int, reason: str = "cancelled") -> bool:
@@ -840,7 +823,6 @@ class ContinuousEngine:
         for seq in self.waiting:
             if seq.req.id == req_id:
                 self.waiting.remove(seq)
-                self._g_queue.set(len(self.waiting))
                 self._m_cancellations.inc()
                 if self.spill_enabled:       # reclaim host-tier bytes
                     self.kv.drop_spill(req_id)
@@ -873,10 +855,6 @@ class ContinuousEngine:
     # -- metric façade ------------------------------------------------------
     # The counters moved into the telemetry registry; these read-only
     # properties keep every pre-telemetry attribute name working.
-
-    @property
-    def dispatch_count(self) -> int:
-        return self._m_dispatches.value
 
     @property
     def dispatches(self) -> int:
@@ -1075,7 +1053,6 @@ class ContinuousEngine:
                                  if s.req.id not in placed)
         if not fresh.any():
             return 0
-        self._g_queue.set(len(self.waiting))
         if self._needs_reset:
             self._m_dispatches.inc()
             self.caches = self.stepper.reset_rows(self.caches, fresh)
@@ -1173,42 +1150,44 @@ class ContinuousEngine:
             self.kv.check_write(s, int(self.slot_len[s]),
                                 int(self.slot_len[s]) + take)
         self._m_dispatches.inc()
-        t_d = self._rec.now()
-        self.caches, _, first, bad_dev = self.stepper.prefill_chunk(
-            self.params, self.caches, toks, self.slot_len, n_valid,
-            block_tables=self.tables)
-        self.slot_len += n_valid
-        self.slot_off += n_valid
-        first_host: "list[np.ndarray]" = []   # read lazily: syncs
-        bad_host: "list[np.ndarray]" = []
-        for s in pre:
-            if self.prefix_sharing:
-                # newly completed full prompt blocks become shareable
-                # (the write dispatch is already issued, and same-device
-                # dispatches execute in issue order)
-                self.kv.publish(s, self._slot_prompt[s],
-                                int(self.slot_len[s]))
-            if self.slot_off[s] < len(self._slot_prompt[s]):
-                continue                      # more prompt next iteration
-            if not first_host:
-                first_host.append(np.asarray(first))
-                bad_host.append(np.asarray(bad_dev))
-            if bad_host[0][s]:
-                # the chunk watchdog is checked at the same lazy sync
-                # that reads the first token — a NaN argmax must never
-                # enter a stream.  Mid-prompt corruption needs no extra
-                # sync: a NaN hidden state propagates through the cache
-                # and the decode watchdog backstops it within one
-                # iteration.
-                self._m_watchdog_trips.inc()
-                self._rec.point("fault", iteration=self.iterations,
-                                what="watchdog", where="prefill_chunk",
-                                slot=s)
-                self._fail(s, "poisoned_logits")
-                continue
-            self._complete_prefill(s, lambda s=s: int(first_host[0][s]))
-        self._rec.span("prefill_chunk", t_d, iteration=self.iterations,
-                       rows=len(pre), tokens=int(n_valid.sum()))
+        rec, it = self._rec, self.iterations
+        with rec.region("prefill_chunk", iteration=it, rows=len(pre),
+                        tokens=int(n_valid.sum())):
+            with rec.region("dispatch", iteration=it):
+                self.caches, _, first, bad_dev = self.stepper.prefill_chunk(
+                    self.params, self.caches, toks, self.slot_len, n_valid,
+                    block_tables=self.tables)
+            self.slot_len += n_valid
+            self.slot_off += n_valid
+            first_host: "list[np.ndarray]" = []   # read lazily: syncs
+            bad_host: "list[np.ndarray]" = []
+            for s in pre:
+                if self.prefix_sharing:
+                    # newly completed full prompt blocks become shareable
+                    # (the write dispatch is already issued, and
+                    # same-device dispatches execute in issue order)
+                    self.kv.publish(s, self._slot_prompt[s],
+                                    int(self.slot_len[s]))
+                if self.slot_off[s] < len(self._slot_prompt[s]):
+                    continue                  # more prompt next iteration
+                if not first_host:
+                    with rec.region("sync", iteration=it):
+                        first_host.append(np.asarray(first))
+                        bad_host.append(np.asarray(bad_dev))
+                if bad_host[0][s]:
+                    # the chunk watchdog is checked at the same lazy sync
+                    # that reads the first token — a NaN argmax must
+                    # never enter a stream.  Mid-prompt corruption needs
+                    # no extra sync: a NaN hidden state propagates
+                    # through the cache and the decode watchdog
+                    # backstops it within one iteration.
+                    self._m_watchdog_trips.inc()
+                    rec.point("fault", iteration=it, what="watchdog",
+                              where="prefill_chunk", slot=s)
+                    self._fail(s, "poisoned_logits")
+                    continue
+                self._complete_prefill(s,
+                                       lambda s=s: int(first_host[0][s]))
 
     def _complete_prefill(self, slot: int, get_first_tok) -> None:
         """Prompt fully consumed: flip the slot to DECODE.  Resumed
@@ -1282,7 +1261,6 @@ class ContinuousEngine:
             self._release_slot(slot)
         seq.preempted = True                  # priority re-admission
         self.waiting.appendleft(seq)
-        self._g_queue.set(len(self.waiting))
         self._m_preemptions.inc()
 
     # -- host KV tier: spill / restore --------------------------------------
@@ -1315,15 +1293,14 @@ class ContinuousEngine:
                                   int(self.slot_len[slot]))
         if plan is None:
             return False
-        t_d = self._rec.now()
-        data = self._capture_blocks(plan.capture_ids)
-        nbytes = self.kv.commit_spill(plan, data)
-        self._m_spills.inc()
-        self._release_slot(slot)
-        self._rec.span("spill", t_d, request_id=seq.req.id, slot=slot,
-                       iteration=self.iterations,
-                       blocks=len(plan.entries),
-                       transferred=len(plan.capture_ids), bytes=nbytes)
+        with self._rec.region("spill", request_id=seq.req.id, slot=slot,
+                              iteration=self.iterations) as span:
+            data = self._capture_blocks(plan.capture_ids)
+            nbytes = self.kv.commit_spill(plan, data)
+            self._m_spills.inc()
+            self._release_slot(slot)
+            span.update(blocks=len(plan.entries),
+                        transferred=len(plan.capture_ids), bytes=nbytes)
         return True
 
     def _restore_slot(self, slot: int, seq: "_Seq") -> int:
@@ -1333,17 +1310,16 @@ class ContinuousEngine:
         re-prefilled, and the restored bytes are bit-identical to what
         was captured, so the resumed stream matches the fault-free one
         exactly."""
-        t_d = self._rec.now()
-        n_tokens, scatter = self.kv.restore(slot, seq.req.id)
-        if scatter:
-            self._scatter_blocks(scatter)
-        self._m_restores.inc()
-        self._m_saved_tokens.inc(n_tokens)
-        self._rec.span("restore", t_d, request_id=seq.req.id, slot=slot,
-                       iteration=self.iterations,
-                       blocks=len(self.kv.block_tables[slot]),
-                       transferred=len(scatter),
-                       bytes=len(scatter) * self.kv.block_bytes)
+        with self._rec.region("restore", request_id=seq.req.id,
+                              slot=slot, iteration=self.iterations) as span:
+            n_tokens, scatter = self.kv.restore(slot, seq.req.id)
+            if scatter:
+                self._scatter_blocks(scatter)
+            self._m_restores.inc()
+            self._m_saved_tokens.inc(n_tokens)
+            span.update(blocks=len(self.kv.block_tables[slot]),
+                        transferred=len(scatter),
+                        bytes=len(scatter) * self.kv.block_bytes)
         return n_tokens
 
     def _capture_blocks(self, ids: "list[int]") -> dict:
@@ -1472,33 +1448,35 @@ class ContinuousEngine:
         for s in np.flatnonzero(active):
             self.kv.check_write(int(s), int(self.slot_len[s]),
                                 int(self.slot_len[s]) + 1)
-        t_d = self._rec.now()
+        rec, it = self._rec, self.iterations
         attempt = attempts_used
-        while True:
-            snapshot = self.caches
-            self._m_dispatches.inc()
-            if attempt > attempts_used:
-                self._m_retry_dispatches.inc()
-            nxt, bad_dev, self.caches = self.stepper.decode(
-                self.params, self.caches, toks, self.slot_len, active,
-                block_tables=self.tables, poison=self._poison(attempt))
-            nxt_host = np.asarray(nxt)        # the one sync per step
-            bad = np.asarray(bad_dev)
-            if not bad.any():
-                break
-            self._m_watchdog_trips.inc()
-            self._rec.point("fault", iteration=self.iterations,
-                            what="watchdog", where="decode",
-                            attempt=attempt - attempts_used)
-            if attempt - attempts_used >= self.dispatch_retries:
-                break        # ladder exhausted: fail the bad rows below
-            self.caches = snapshot            # discard poisoned writes
-            time.sleep(self.retry_backoff_s
-                       * (1 << (attempt - attempts_used)))
-            attempt += 1
-        self._rec.span("decode", t_d, iteration=self.iterations,
-                       rows=int(active.sum()),
-                       attempts=attempt - attempts_used + 1)
+        with rec.region("decode", iteration=it,
+                        rows=int(active.sum())) as span:
+            while True:
+                snapshot = self.caches
+                self._m_dispatches.inc()
+                if attempt > attempts_used:
+                    self._m_retry_dispatches.inc()
+                with rec.region("dispatch", iteration=it):
+                    nxt, bad_dev, self.caches = self.stepper.decode(
+                        self.params, self.caches, toks, self.slot_len,
+                        active, block_tables=self.tables,
+                        poison=self._poison(attempt))
+                with rec.region("sync", iteration=it):
+                    nxt_host = np.asarray(nxt)    # the one sync per step
+                    bad = np.asarray(bad_dev)
+                if not bad.any():
+                    break
+                self._m_watchdog_trips.inc()
+                rec.point("fault", iteration=it, what="watchdog",
+                          where="decode", attempt=attempt - attempts_used)
+                if attempt - attempts_used >= self.dispatch_retries:
+                    break    # ladder exhausted: fail the bad rows below
+                self.caches = snapshot        # discard poisoned writes
+                time.sleep(self.retry_backoff_s
+                           * (1 << (attempt - attempts_used)))
+                attempt += 1
+            span.update(attempts=attempt - attempts_used + 1)
         self.slot_len += active
         for s in np.flatnonzero(bad):
             self._fail(int(s), "poisoned_logits")
@@ -1651,16 +1629,25 @@ class ContinuousEngine:
                 int(self.slot_len[s]) + min(n, int(budget[s])))
         self._m_dispatches.inc()
         self._m_megasteps.inc()
-        self._h_megastep_len.observe(n)
-        t_d = self._rec.now()
-        snapshot = self.caches                # free O(1) checkpoint
-        toks_dev, act_dev, bad_dev, self.caches = self.stepper.megastep(
-            self.params, self.caches, self.slot_last, self.slot_len,
-            active, budget, forced, n_forced, eos_ids,
-            block_tables=self.tables, poison=self._poison(0))
-        toks_out = np.asarray(toks_dev)       # (n, B) — the ONE sync
-        act_out = np.asarray(act_dev)
-        bad = np.asarray(bad_dev)
+        rec, it = self._rec, self.iterations
+        rows = int(active.sum())
+        with rec.region("megastep", iteration=it, n=n) as span:
+            snapshot = self.caches            # free O(1) checkpoint
+            with rec.region("dispatch", iteration=it):
+                toks_dev, act_dev, bad_dev, self.caches = \
+                    self.stepper.megastep(
+                        self.params, self.caches, self.slot_last,
+                        self.slot_len, active, budget, forced, n_forced,
+                        eos_ids, block_tables=self.tables,
+                        poison=self._poison(0))
+            with rec.region("sync", iteration=it):
+                toks_out = np.asarray(toks_dev)   # (n, B) — the ONE sync
+                act_out = np.asarray(act_dev)
+                bad = np.asarray(bad_dev)
+            steps = act_out.sum(axis=0).astype(np.int32)
+            # a discarded scan commits no step
+            executed = 0 if bad.any() else int(steps.max())
+            span.update(executed=executed, rows=rows)
         if bad.any():
             # watchdog tripped inside the fused scan: one poisoned step
             # contaminates every later step of that row, so the whole
@@ -1673,21 +1660,25 @@ class ContinuousEngine:
             self.caches = snapshot
             self._m_watchdog_trips.inc()
             self._m_megastep_fallbacks.inc()
-            self._rec.point("fault", iteration=self.iterations,
-                            what="watchdog", where="megastep", n=n)
+            rec.point("fault", iteration=it, what="watchdog",
+                      where="megastep", n=n)
             for s in np.flatnonzero(active):
                 self._release_reservation(int(s))
             self._grow_or_preempt()
             self._decode(attempts_used=1)
             return
         now = time.perf_counter()             # post-reconciliation stamp
-        steps = act_out.sum(axis=0).astype(np.int32)
-        executed = int(steps.max())
         self._m_megastep_steps.inc(executed)
         self._m_fused_iterations.inc(executed)
-        self._rec.span("megastep", t_d, iteration=self.iterations,
-                       n=n, executed=executed, rows=int(active.sum()))
-        t_r = self._rec.now()
+        with rec.region("reconcile", iteration=it, rows=rows):
+            self._reconcile(active, prefilling, steps, toks_out, n_forced,
+                            now)
+
+    def _reconcile(self, active, prefilling, steps, toks_out, n_forced,
+                   now: float) -> None:
+        """Replay a committed megastep's bookkeeping on the host: extend
+        the streams, stamp first tokens, finish rows, and return the
+        blocks reserved for steps a row did not take."""
         self.slot_len += steps
         for s in np.flatnonzero(active):
             s = int(s)
@@ -1737,9 +1728,6 @@ class ContinuousEngine:
                        if self.slot_phase[s] == PREFILL else 0)
             if self.kv.release_to(s, keep):
                 self._refresh_table(s)
-        self._rec.span("reconcile", t_r, iteration=self.iterations,
-                       rows=int(active.sum()))
-
     def _release_reservation(self, slot: int) -> None:
         """Return an occupied slot's reserved-but-unwritten blocks —
         everything past its written watermark (plus a prefilling row's
@@ -1774,7 +1762,6 @@ class ContinuousEngine:
         self.completed[seq.req.id] = comp
         self._drainable.append(comp)
         self._m_resolved.inc()
-        self._h_generated.observe(len(seq.gen))
         self._rec.point("complete", request_id=seq.req.id,
                         iteration=self.iterations,
                         status=status, reason=reason,
@@ -1812,26 +1799,26 @@ class ContinuousEngine:
         if not rec.enabled:          # no-op fast path: zero clock reads
             self._step()
             return
-        t_it = rec.now()
-        try:
-            self._step()
-        finally:
-            extra = {}
-            if self.kv.host_budget:
-                extra = {"host_blocks": self.kv.host_blocks_live,
-                         "host_bytes": self.kv.host_in_use}
-            rec.span("iteration", t_it, iteration=self.iterations,
-                     kv_blocks=self.kv.live_blocks,
-                     kv_bytes=self.kv.in_use,
-                     active=self.num_active,
-                     waiting=len(self.waiting), **extra)
+        with rec.region("iteration", iteration=self.iterations) as span:
+            try:
+                self._step()
+            finally:
+                span.update(kv_blocks=self.kv.live_blocks,
+                            kv_bytes=self.kv.in_use,
+                            active=self.num_active,
+                            waiting=len(self.waiting))
+                if self.kv.host_budget:
+                    span.update(host_blocks=self.kv.host_blocks_live,
+                                host_bytes=self.kv.host_in_use)
 
     def _step(self) -> None:
+        rec, it = self._rec, self.iterations
         if self.faults is not None:
-            self._apply_faults(self.faults.events_at(self.iterations))
-        if self._deadlines_armed:
-            self._expire_deadlines()
-        admitted = self._admit()
+            self._apply_faults(self.faults.events_at(it))
+        with rec.region("schedule", iteration=it):
+            if self._deadlines_armed:
+                self._expire_deadlines()
+            admitted = self._admit()
         if self.num_active == 0:
             if admitted == 0 and self.waiting:
                 need = min(self._resume_need(s) for s in self.waiting)
@@ -1842,13 +1829,13 @@ class ContinuousEngine:
                     # the restore's ETA, so a wedged-looking run can be
                     # told apart from a deliberately idling one.
                     self._m_stalls.inc()
-                    if self._rec.enabled:
-                        self._rec.point(
-                            "stalled", iteration=self.iterations,
+                    if rec.enabled:
+                        rec.point(
+                            "stalled", iteration=it,
                             cause="budget_shrunk", need_bytes=need,
                             waiting=len(self.waiting),
                             restore_eta_iteration=self.faults
-                            .next_budget_recovery(self.iterations, need))
+                            .next_budget_recovery(it, need))
                     return
                 raise MemoryError(
                     f"no request fits: smallest pending need is "
@@ -1856,10 +1843,10 @@ class ContinuousEngine:
             if admitted == 0:
                 return
         self._prefill()
-        n, plans = self._plan_megastep()
+        with rec.region("plan", iteration=it):
+            n, plans = self._plan_megastep()
         if n >= 2 and self.faults is not None:
-            posted = self.faults.events_at(self.iterations,
-                                           when="post_reserve")
+            posted = self.faults.events_at(it, when="post_reserve")
             if posted:
                 # a cancel landing right after the megastep bulk
                 # reserve: return every slot's reservation, apply the
@@ -1873,7 +1860,8 @@ class ContinuousEngine:
         if n >= 2:
             self._megastep(n, plans)
         else:
-            self._grow_or_preempt()
+            with rec.region("plan", iteration=it):
+                self._grow_or_preempt()
             self._decode()
 
     def _apply_faults(self, events) -> None:
@@ -1935,7 +1923,6 @@ class ContinuousEngine:
                 if self.spill_enabled:
                     self.kv.drop_spill(seq.req.id)
                 self._resolve(seq, "failed", "max_iters")
-            self._g_queue.set(0)
         return self.completed
 
     def assert_quiescent(self) -> None:

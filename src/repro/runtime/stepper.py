@@ -35,6 +35,11 @@ executables never contain injection code.
 Trace counters are incremented inside the traced Python bodies (which
 run only at trace time), so ``chunk_traces`` / ``decode_traces`` observe
 XLA retraces directly; ``dispatches`` counts calls.
+
+The programs keep fixed names (:data:`DECODE_PROGRAM`,
+:data:`MEGASTEP_PROGRAM`, :data:`CHUNK_PROGRAM`), by which a profiler
+trace's reduction finds them, and the sampling tail of every step runs
+under the ``sample`` named scope (the model's parts carry their own).
 """
 
 from __future__ import annotations
@@ -46,6 +51,20 @@ import numpy as np
 from .sampling import (greedy_serving, logits_watchdog, megastep_advance,
                        poison_logits, select_tokens)
 from .telemetry import MetricsRegistry
+
+#: The served step programs' names, as XLA and the profiler show them
+#: (``jax.jit`` names a program ``jit_<function>``): the single decode
+#: step, the decode megastep's scan and the chunked prefill.  Profiler
+#: reductions find the programs by these names.
+DECODE_PROGRAM = "jit_step"
+MEGASTEP_PROGRAM = "jit_run"
+CHUNK_PROGRAM = "jit_run_chunk"
+
+
+def _program(fn, name: str):
+    """``jax.jit`` of ``fn`` as the program named ``name``."""
+    fn.__name__ = name.removeprefix("jit_")
+    return jax.jit(fn)
 
 
 def _device(x, dtype):
@@ -92,12 +111,18 @@ class Stepper:
         # re-appearing would mean a RE-trace (tests assert counters ==
         # set sizes, i.e. one trace per distinct scan length)
         self.megastep_sizes: "set[tuple[bool, int]]" = set()
-        self._decode = jax.jit(self._make_decode(paged=False))
-        self._chunk = jax.jit(self._make_chunk(paged=False))
-        self._decode_paged = jax.jit(self._make_decode(paged=True))
-        self._chunk_paged = jax.jit(self._make_chunk(paged=True))
-        self._mega = jax.jit(self._make_megastep(paged=False))
-        self._mega_paged = jax.jit(self._make_megastep(paged=True))
+        self._decode = _program(self._make_decode(paged=False),
+                                DECODE_PROGRAM)
+        self._chunk = _program(self._make_chunk(paged=False),
+                               CHUNK_PROGRAM)
+        self._decode_paged = _program(self._make_decode(paged=True),
+                                      DECODE_PROGRAM)
+        self._chunk_paged = _program(self._make_chunk(paged=True),
+                                     CHUNK_PROGRAM)
+        self._mega = _program(self._make_megastep(paged=False),
+                              MEGASTEP_PROGRAM)
+        self._mega_paged = _program(self._make_megastep(paged=True),
+                                    MEGASTEP_PROGRAM)
         self._reset = jax.jit(self._make_reset())
         # poisoned twins — identical math plus an in-trace NaN injection
         # (sampling.poison_logits) — are built lazily on the first
@@ -150,9 +175,10 @@ class Stepper:
         key = (kind, paged)
         fn = self._poison_jits.get(key)
         if fn is None:
-            maker = {"decode": self._make_decode,
-                     "mega": self._make_megastep}[kind]
-            fn = jax.jit(maker(paged=paged, poisoned=True))
+            maker, name = {"decode": (self._make_decode, DECODE_PROGRAM),
+                           "mega": (self._make_megastep,
+                                    MEGASTEP_PROGRAM)}[kind]
+            fn = _program(maker(paged=paged, poisoned=True), name)
             self._poison_jits[key] = fn
         return fn
 
@@ -174,10 +200,11 @@ class Stepper:
             if tables is not None:
                 batch["block_tables"] = tables
             logits, caches = decode(params, caches, batch)
-            if poisoned:
-                logits = poison_logits(logits, poison)
-            bad = logits_watchdog(logits, active)
-            return select_tokens(logits, active, toks), bad, caches
+            with jax.named_scope("sample"):
+                if poisoned:
+                    logits = poison_logits(logits, poison)
+                bad = logits_watchdog(logits, active)
+                return select_tokens(logits, active, toks), bad, caches
 
         return step
 
@@ -224,9 +251,10 @@ class Stepper:
                 if tables is not None:
                     batch["block_tables"] = tables
                 logits, caches = decode(params, caches, batch)
-                first = jnp.where(i == n_valid - 1,
-                                  greedy_serving(logits), first)
-                bad = bad | logits_watchdog(logits, active)
+                with jax.named_scope("sample"):
+                    first = jnp.where(i == n_valid - 1,
+                                      greedy_serving(logits), first)
+                    bad = bad | logits_watchdog(logits, active)
                 lens = lens + active.astype(jnp.int32)
                 return (caches, lens, first, bad), None
 
@@ -286,13 +314,16 @@ class Stepper:
                 if tables is not None:
                     batch["block_tables"] = tables
                 logits, caches = decode(params, caches, batch)
-                if poisoned:
-                    # the fault fires at the megastep's FIRST fused
-                    # iteration — the engine iteration it was keyed to
-                    logits = poison_logits(logits, poison & (s == 0))
-                bad = bad | logits_watchdog(logits, active)
-                nxt, nactive, budget = megastep_advance(
-                    logits, last, active, budget, n_forced, eos_ids, s)
+                with jax.named_scope("sample"):
+                    if poisoned:
+                        # the fault fires at the megastep's FIRST fused
+                        # iteration — the engine iteration it was keyed
+                        # to
+                        logits = poison_logits(logits, poison & (s == 0))
+                    bad = bad | logits_watchdog(logits, active)
+                    nxt, nactive, budget = megastep_advance(
+                        logits, last, active, budget, n_forced, eos_ids,
+                        s)
                 lens = lens + active.astype(jnp.int32)
                 # emit the pre-update mask: which rows EXECUTED this
                 # step (wrote their cache and, on gen steps, a token)

@@ -18,14 +18,20 @@ that make a serving run diagnosable:
 * **Span recorder** (:class:`SpanRecorder`) — structured events with
   monotonic timestamps, per-request and per-iteration.  The taxonomy is
   fixed (:data:`SPAN_KINDS`): ``submit`` / ``admit`` / ``first_token``
-  / ``prefill_chunk`` / ``decode`` / ``megastep`` / ``reconcile`` /
-  ``preempt`` / ``spill`` / ``restore`` / ``stalled`` / ``fault`` /
-  ``complete`` / ``iteration`` (engine) and ``segment`` (hetero
-  executor).  Recording is **disabled by default**: every hook site is
-  a single ``enabled`` check, ``now()`` returns ``0.0`` without touching
-  the clock, and nothing allocates — the disabled hot path is
-  micro-benchmarked by ``benchmarks/serving.py`` and gated under 2 % of
-  per-token wall time by ``benchmarks/gate.py``.
+  / ``schedule`` / ``plan`` / ``prefill_chunk`` / ``decode`` /
+  ``megastep`` / ``dispatch`` / ``sync`` / ``reconcile`` / ``preempt``
+  / ``spill`` / ``restore`` / ``stalled`` / ``fault`` / ``complete`` /
+  ``iteration`` (engine) and ``segment`` (hetero executor).  The
+  engine's duration spans are :meth:`SpanRecorder.region` contexts,
+  which also write each span into the profiler's own trace as a
+  ``jax.profiler.TraceAnnotation`` named ``engine.<kind>``, so a
+  ``jax.profiler`` capture shows the engine's phases on the device
+  trace's clock.  Recording is **disabled by default**: every hook site
+  is a single ``enabled`` check, ``now()`` returns ``0.0`` without
+  touching the clock, ``region()`` returns one shared no-op context,
+  and nothing allocates — the disabled hot path is micro-benchmarked by
+  ``benchmarks/serving.py`` and gated under 2 % of per-token wall time
+  by ``benchmarks/gate.py``.
 
 * **Exporters** — ``MetricsRegistry.snapshot()`` (JSON),
   :func:`request_timelines` (per-request lifecycle), and
@@ -51,6 +57,8 @@ import json
 import time
 from bisect import bisect_left
 
+import jax
+
 #: Every structured-event kind any component can emit.  The engine emits
 #: all but "segment" (the hetero executor's per-segment span); the
 #: schema check in tests/test_telemetry.py validates every recorded
@@ -62,17 +70,25 @@ from bisect import bisect_left
 #: reached the host (submit -> first_token is the open-loop harness's
 #: TTFT-under-load signal); ``cache_evict`` marks a prefix-cache block
 #: leaving the device pool (block/byte args, ``to_host`` when the host
-#: tier gave it a second chance).
-SPAN_KINDS = ("submit", "admit", "first_token", "prefill_chunk",
-              "decode", "megastep", "reconcile", "preempt", "spill",
-              "restore", "stalled", "fault", "complete", "iteration",
-              "segment", "cache_evict")
+#: tier gave it a second chance).  The engine's phases of one
+#: iteration: ``schedule`` (admission and placement, their table
+#: refresh), ``plan`` (the megastep reserve or the sync path's grow /
+#: preempt), ``dispatch`` (a jitted step call until it returns: argument
+#: conversion, transfer, enqueue), ``sync`` (the blocking reads of a
+#: step's outputs) and ``reconcile``; ``dispatch`` and ``sync`` nest in
+#: their ``prefill_chunk`` / ``decode`` / ``megastep`` span, which
+#: nests in its ``iteration``.
+SPAN_KINDS = ("submit", "admit", "first_token", "schedule", "plan",
+              "prefill_chunk", "decode", "megastep", "dispatch", "sync",
+              "reconcile", "preempt", "spill", "restore", "stalled",
+              "fault", "complete", "iteration", "segment", "cache_evict")
 
 #: Kinds recorded with a duration (``ts`` + ``dur``); the rest are
 #: instantaneous points (``ts`` only).
-DURATION_KINDS = frozenset({"iteration", "prefill_chunk", "decode",
-                            "megastep", "reconcile", "spill", "restore",
-                            "segment"})
+DURATION_KINDS = frozenset({"iteration", "schedule", "plan",
+                            "prefill_chunk", "decode", "megastep",
+                            "dispatch", "sync", "reconcile", "spill",
+                            "restore", "segment"})
 POINT_KINDS = frozenset(k for k in SPAN_KINDS if k not in DURATION_KINDS)
 
 #: Kinds that always carry a ``request_id``.
@@ -204,13 +220,77 @@ class MetricsRegistry:
         return out
 
 
+class _Region:
+    """One enabled :meth:`SpanRecorder.region`: a profiler annotation
+    around the span, and the span's event appended on exit.  ``update``
+    sets the event's args while it is open."""
+
+    __slots__ = ("rec", "kind", "iteration", "request_id", "slot",
+                 "args", "t0", "annotation")
+
+    def __init__(self, rec, kind, iteration, request_id, slot, args):
+        self.rec = rec
+        self.kind = kind
+        self.iteration = iteration
+        self.request_id = request_id
+        self.slot = slot
+        self.args = args
+
+    def update(self, **args) -> None:
+        self.args.update(args)
+
+    def __enter__(self) -> "_Region":
+        name = f"engine.{self.kind}"
+        if self.iteration is None:
+            ann = jax.profiler.TraceAnnotation(name)
+        elif self.kind == "iteration":
+            ann = jax.profiler.StepTraceAnnotation(
+                name, step_num=self.iteration)
+        else:
+            ann = jax.profiler.TraceAnnotation(name,
+                                               iteration=self.iteration)
+        ann.__enter__()
+        self.annotation = ann
+        # the clock is read inside the annotation, so the recorded span
+        # lies within the profiler's
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        now = time.perf_counter()
+        self.annotation.__exit__(*exc)
+        e = self.rec._event(self.kind, self.t0, self.iteration,
+                            self.request_id, self.slot, self.args)
+        e["dur"] = now - self.t0
+
+
+class _NoRegion:
+    """The disabled recorder's region: enters, takes args and exits
+    without reading the clock or building anything."""
+
+    __slots__ = ()
+
+    def update(self, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_NoRegion":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NO_REGION = _NoRegion()
+
+
 class SpanRecorder:
     """Structured span/point event recorder with a no-op fast path.
 
     Disabled (the default), every hook is one attribute check:
-    ``now()`` returns 0.0 without reading the clock and ``point`` /
-    ``span`` return before building anything.  Enabled, events append
-    to a host-side list as plain dicts::
+    ``now()`` returns 0.0 without reading the clock, ``point`` /
+    ``span`` return before building anything, and ``region`` returns
+    one shared no-op context.  Enabled, events append to a host-side
+    list as plain dicts::
 
         {"kind": ..., "ts": <monotonic s>, ["dur": <s>,]
          ["iteration": i,] ["request_id": r,] ["slot": s,]
@@ -260,6 +340,19 @@ class SpanRecorder:
         now = time.perf_counter()
         e = self._event(kind, t0, iteration, request_id, slot, args)
         e["dur"] = now - t0
+
+    def region(self, kind: str, *, iteration=None, request_id=None,
+               slot=None, **args):
+        """A duration event as a context: the event that :meth:`span`
+        would record for the ``with`` block, which is also a
+        ``jax.profiler.TraceAnnotation`` named ``engine.<kind>`` carrying
+        the iteration number (a ``StepTraceAnnotation`` for
+        ``iteration``), so a profiler capture shows it on the device
+        trace's clock.  The context's ``update`` adds args before it
+        exits."""
+        if not self.enabled:
+            return _NO_REGION
+        return _Region(self, kind, iteration, request_id, slot, args)
 
 
 class Telemetry:
@@ -323,10 +416,11 @@ def chrome_trace(events: "list[dict]") -> dict:
 
     Mapping:
 
-    * duration kinds (``iteration``, ``prefill_chunk``, ``decode``,
-      ``megastep``, ``reconcile``, ``segment``) → complete events
-      (``ph: "X"``) on the engine track; dispatch spans nest inside
-      their iteration's slice,
+    * duration kinds (``iteration``, ``schedule``, ``plan``,
+      ``prefill_chunk``, ``decode``, ``megastep``, ``dispatch``,
+      ``sync``, ``reconcile``, ``segment``, ...) → complete events
+      (``ph: "X"``) on the engine track; phase and dispatch spans nest
+      inside their iteration's slice,
     * ``submit``/``complete`` → nestable async begin/end (``"b"``/
       ``"e"``, ``id`` = request id) with ``admit``/``preempt`` as async
       instants (``"n"``) — one async lifecycle per request,

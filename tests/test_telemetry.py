@@ -123,6 +123,69 @@ def test_recorder_disabled_is_inert():
     assert rec.events == []
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler``'s annotations: records each one's
+    name, metadata and entry/exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def make(self, name, **meta):
+        log = self.log
+
+        class Ann:
+            def __enter__(self):
+                log.append(("enter", name, meta))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, meta))
+
+        return Ann()
+
+
+def test_region_records_and_annotates(monkeypatch):
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.make)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                        lambda name, step_num: ann.make(name,
+                                                        step=step_num))
+    rec = SpanRecorder(True)
+    with rec.region("iteration", iteration=4, active=2) as it:
+        with rec.region("sync", iteration=4):
+            pass
+        it.update(waiting=1)
+        it.update(kv_blocks=3)
+    sync, iteration = rec.events
+    assert sync["kind"] == "sync" and "args" not in sync
+    assert iteration == {"kind": "iteration", "ts": iteration["ts"],
+                         "dur": iteration["dur"], "iteration": 4,
+                         "args": {"active": 2, "waiting": 1,
+                                  "kv_blocks": 3}}
+    assert iteration["ts"] <= sync["ts"]
+    assert sync["ts"] + sync["dur"] <= iteration["ts"] + iteration["dur"]
+    # one profiler annotation per span, named engine.<kind>, nested as
+    # the spans are, the iteration number as its metadata
+    assert ann.log == [("enter", "engine.iteration", {"step": 4}),
+                       ("enter", "engine.sync", {"iteration": 4}),
+                       ("exit", "engine.sync", {"iteration": 4}),
+                       ("exit", "engine.iteration", {"step": 4})]
+    # a span left by an exception is recorded all the same
+    with pytest.raises(KeyError):
+        with rec.region("decode", rows=1):
+            raise KeyError("x")
+    assert rec.events[-1]["kind"] == "decode"
+    assert ann.log[-1] == ("exit", "engine.decode", {})
+
+
+def test_disabled_region_is_one_shared_noop():
+    rec = SpanRecorder(False)
+    r = rec.region("sync", iteration=1)
+    assert r is rec.region("megastep", iteration=2, n=8)
+    with r as span:
+        span.update(executed=3, rows=2)
+    assert rec.events == []
+
+
 def test_recorder_event_schema():
     rec = SpanRecorder(True)
     t0 = rec.now()
@@ -365,6 +428,37 @@ def test_engine_span_taxonomy(model, shared_stepper):
         for rid, evs in tl.items():
             assert evs[0]["kind"] == "submit"
             assert evs[-1]["kind"] == "complete"
+
+
+class _NoClock:
+    """A ``time`` module whose clock must not be read."""
+
+    def perf_counter(self):
+        raise AssertionError("the disabled recorder read the clock")
+
+
+@pytest.mark.parametrize("m", [1, 8])
+def test_untraced_engine_reads_no_clock_and_builds_no_annotation(
+        model, shared_stepper, monkeypatch, m):
+    """With ``Telemetry(trace=False)`` every phase region of the engine
+    (megastep path at m=8, sync decode path at m=1, chunked prefill in
+    both) is the shared no-op: the recorder reads no clock and no
+    profiler annotation is ever constructed."""
+    cfg, _, _ = model
+
+    def boom(*a, **k):
+        raise AssertionError("an annotation was constructed")
+
+    import repro.runtime.telemetry as telemetry
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", boom)
+    monkeypatch.setattr(telemetry, "time", _NoClock())
+    tele = Telemetry(trace=False)
+    eng = _engine(model, shared_stepper, megastep=m, telemetry=tele,
+                  prefill_chunk=4)
+    out = _run(eng, cfg)
+    assert all(c.ok for c in out.values())
+    assert tele.events == []
 
 
 def test_fused_iterations_semantics(model, shared_stepper):
