@@ -267,7 +267,7 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, dtype):
 
 def decode_step_attention(params, cfg, x, cache, cache_len,
                           positions3=None, window: int = 0, active=None,
-                          block_tables=None):
+                          block_tables=None, layer=None):
     """One-token decode: x (B, 1, d) against cache k/v (B, slots, K, D).
 
     ``cache_len`` is the number of tokens already generated/prefilled;
@@ -295,6 +295,11 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
     new slot tenant needs NO cache reset on attention-only models —
     the engine skips the reset dispatch unless SSM/conv state exists.
 
+    ``layer`` (traced scalar) marks a paged cache whose pools are the
+    layer scan's stacked ``(n_rep, nb + 1, bs, K, D)`` carry: the step
+    reads and writes that layer's pool through it (see
+    ``_decode_step_attention_paged``).
+
     Returns ``(out (B,1,d), new_cache)``.
     """
     B = x.shape[0]
@@ -306,7 +311,7 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
                 "(B, blocks_per_seq) block table")
         return _decode_step_attention_paged(
             params, cfg, x, cache, cache_len, block_tables, positions3,
-            window, active)
+            window, active, layer)
     slots = cache["k"].shape[1]
     if cache_len.ndim == 1:
         return _decode_step_attention_vec(params, cfg, x, cache, cache_len,
@@ -379,7 +384,8 @@ def _decode_step_attention_vec(params, cfg, x, cache, cache_len,
 
 
 def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
-                                 block_tables, positions3, window, active):
+                                 block_tables, positions3, window, active,
+                                 layer=None):
     """Vector decode step over a physically paged KV pool.
 
     ``cache`` holds one block pool per layer (``k_pool``/``v_pool``,
@@ -393,13 +399,20 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
     bit-identical to the dense cache (garbage in unwritten/scratch
     positions is masked to an exact 0 contribution).
 
+    With ``layer`` given, the pools are every scanned layer's, stacked
+    ``(n_rep, nb + 1, bs, K, D)``, and ``layer`` leads every index: the
+    token lands in place in the carried stack and the gather reads only
+    the table's blocks, so no layer's pool is sliced out or written
+    back whole.
+
     The engine guarantees a written block is never shared (prefix-shared
     blocks are full, immutable and live strictly below every row's write
     position — see BlockKVCache.check_write).
     """
     B = x.shape[0]
     pool_k, pool_v = cache["k_pool"], cache["v_pool"]
-    nb_total, bs = pool_k.shape[0], pool_k.shape[1]
+    lead = () if layer is None else (layer,)
+    nb_total, bs = pool_k.shape[len(lead)], pool_k.shape[len(lead) + 1]
     scratch = nb_total - 1
     bps = block_tables.shape[1]
     T = bps * bs
@@ -415,15 +428,15 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
         offs = cache_len % bs
         if active is not None:
             bids = jnp.where(active, bids, scratch)
-        pool_k = pool_k.at[bids, offs].set(
+        pool_k = pool_k.at[lead + (bids, offs)].set(
             k_new[:, 0].astype(pool_k.dtype))
-        pool_v = pool_v.at[bids, offs].set(
+        pool_v = pool_v.at[lead + (bids, offs)].set(
             v_new[:, 0].astype(pool_v.dtype))
 
     # gather the row's cache view through its block table
     with jax.named_scope("kv_gather"):
-        k = pool_k[block_tables].reshape(B, T, *pool_k.shape[2:])
-        v = pool_v[block_tables].reshape(B, T, *pool_v.shape[2:])
+        k = pool_k[lead + (block_tables,)].reshape(B, T, *pool_k.shape[-2:])
+        v = pool_v[lead + (block_tables,)].reshape(B, T, *pool_v.shape[-2:])
 
     t = jnp.arange(T, dtype=jnp.int32)[None, :]           # (1, T)
     valid = t <= positions

@@ -136,13 +136,15 @@ def init_paged_block_cache(cfg, kind, batch, num_blocks, block_size,
 
 def decode_block(params, cfg, x, cache, kind, cache_len,
                  positions3=None, moe_impl="ragged", mesh=None,
-                 active=None, block_tables=None):
+                 active=None, block_tables=None, layer=None):
     """Single-token decode block.  x: (B, 1, d).
 
     ``active`` (B,) bool gates per-row cache updates (continuous
     batching: inactive slot-table rows must not mutate their caches).
     ``block_tables`` (B, blocks_per_seq) routes paged attention caches
     (see attention.init_paged_kv_cache); ignored by dense caches.
+    ``layer`` indexes a paged cache whose pools are stacked over the
+    scanned layers (see ``attention.decode_step_attention``).
 
     ``cache_len`` and ``active`` are *scan carries* in the serving
     runtime: the decode megastep threads them through ``lax.scan`` with
@@ -167,7 +169,8 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
             y, cache = decode_step_attention(params["attn"], cfg, h, cache,
                                              cache_len, positions3,
                                              active=active,
-                                             block_tables=block_tables)
+                                             block_tables=block_tables,
+                                             layer=layer)
             x = x + y
     else:
         with jax.named_scope("ssm"):

@@ -185,11 +185,18 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     were built by :func:`init_paged_caches` (one table routes every
     layer's pool).
 
+    Each paged attention pool of the period rides in the layer scan's
+    carry, stacked ``(n_rep, nb + 1, bs, K, D)`` as the cache holds it,
+    and the scan hands the block its layer index: the new token is
+    written in place and K/V are gathered through the table, so a step
+    neither slices a layer's pool out nor writes one back.  Every other
+    cache (dense per-slot KV, SSM/conv state) is scanned as ``xs`` and
+    ``ys``.
+
     The parts run under ``jax.named_scope``s (``embed``, each block's
     in ``decode_block``, ``norm``, ``lm_head``); the layer scan itself
-    is left outside every scope, so its per-layer slices and write-backs
-    of the stacked weights and caches, and the copies the compiler adds
-    for the carried pool, carry no model scope.
+    is left outside every scope, so its per-layer slices of the stacked
+    weights (and of scanned caches) carry no model scope.
     """
     pattern, prefix_len, period, n_rep = structure(cfg)
     with jax.named_scope("embed"):
@@ -206,22 +213,33 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     new_period = caches["period"]
     if n_rep:
         kinds = [pattern[prefix_len + j] for j in range(period)]
+        carried = ["k_pool" in c for c in caches["period"]]
+        pools = tuple(c if k else None
+                      for c, k in zip(caches["period"], carried))
+        scanned = tuple(None if k else c
+                        for c, k in zip(caches["period"], carried))
 
-        def body(x, scanned):
-            layer_params, layer_caches = scanned
-            new_caches = []
+        def body(carry, xs):
+            x, pools = carry
+            layer_params, layer_caches, layer = xs
+            pools, new_caches = list(pools), []
             for j in range(period):
-                x, c = decode_block(layer_params[j], cfg, x,
-                                    layer_caches[j], kinds[j], cache_len,
-                                    positions3, moe_impl, mesh, active,
-                                    block_tables)
-                new_caches.append(c)
-            return x, tuple(new_caches)
+                cache = pools[j] if carried[j] else layer_caches[j]
+                x, c = decode_block(layer_params[j], cfg, x, cache,
+                                    kinds[j], cache_len, positions3,
+                                    moe_impl, mesh, active, block_tables,
+                                    layer if carried[j] else None)
+                if carried[j]:
+                    pools[j] = c
+                new_caches.append(None if carried[j] else c)
+            return (x, tuple(pools)), tuple(new_caches)
 
-        x, new_period = jax.lax.scan(
-            body, x, (tuple(params["period"]), tuple(caches["period"])),
+        (x, pools), ys = jax.lax.scan(
+            body, (x, pools),
+            (tuple(params["period"]), scanned, jnp.arange(n_rep)),
             **runtime_flags.scan_kwargs())
-        new_period = list(new_period)
+        new_period = [p if k else y
+                      for p, y, k in zip(pools, ys, carried)]
 
     norm = make_norm(cfg.norm_type)
     with jax.named_scope("norm"):

@@ -569,9 +569,11 @@ class ContinuousEngine:
 
     **Robustness** (see ``runtime/faults.py``): every dispatch carries
     an in-trace NaN watchdog; a poisoned result degrades down a ladder —
-    megastep discarded (the pre-dispatch cache pytree is a free
+    megastep discarded (the pre-dispatch cache pytree is the
     checkpoint: the jits do not donate cache args, so caches update
-    functionally), N=1 sync retries with bounded exponential backoff
+    functionally; its price is one whole-pool copy per pool and
+    dispatch, made on entry, where the layer scan then writes each
+    token in place), N=1 sync retries with bounded exponential backoff
     (``dispatch_retries`` / ``retry_backoff_s``), then only the affected
     rows fail with ``reason="poisoned_logits"``.  The block-pool budget
     can shrink/restore mid-run (``faults``); the engine preempts and
@@ -1430,7 +1432,8 @@ class ContinuousEngine:
         in-dispatch watchdog flags a row, the dispatch is discarded (the
         pre-dispatch cache pytree is the checkpoint — the jits do not
         donate cache args, so caches update functionally and holding the
-        old reference is O(1)) and retried up to ``dispatch_retries``
+        old reference is O(1); each dispatch pays for it with one copy
+        of every KV pool on entry) and retried up to ``dispatch_retries``
         times with exponential backoff; exhausting the ladder commits
         the clean rows from the final dispatch (rows are computationally
         independent) and fails only the affected rows.

@@ -5,7 +5,8 @@ collective traffic; per the assignment we parse the (optimized) HLO and
 sum the *result* shapes of every collective op as the bytes-moved proxy
 (for all-reduce the result equals the operand; for all-gather it is the
 gathered size, i.e. the received volume — a per-device upper bound that
-is the quantity the ICI roofline term wants).
+is the quantity the ICI roofline term wants).  ``loop_body_ops`` lists
+what runs inside a compiled program's loop bodies.
 """
 
 from __future__ import annotations
@@ -102,3 +103,55 @@ def op_histogram(hlo_text: str, top: int = 20):
     for o in ops:
         hist[o] += 1
     return sorted(hist.items(), key=lambda kv: -kv[1])[:top]
+
+
+# a computation's header starts in column 0 and opens its body:
+#   %fused_computation.3 (param_0: bf16[2,8]) -> bf16[2,8] {
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+# an array-valued instruction (a tuple's shape holds spaces and is
+# skipped):   %copy.5 = bf16[2,65,16]{2,1,0:T(8,128)} copy(%p), ...
+_ARRAY_INSTR_RE = re.compile(
+    r"^\s+(?:ROOT )?%?[\w.\-]+ = [a-z0-9]+\[([0-9,]*)\]\S* "
+    r"([a-z][a-z0-9-]*)\(")
+_CALLED_RE = re.compile(
+    r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}")
+
+
+def loop_body_ops(hlo_text: str) -> "list[tuple[str, tuple[int, ...]]]":
+    """``(opcode, result dims)`` of every array-valued instruction that
+    runs inside a ``while`` loop's body — the fusions, reductions and
+    nested loops the body calls included — so a test can ask what a
+    scanned step moves per iteration, whatever the backend fused it
+    into."""
+    comps: dict[str, list[str]] = {}
+    lines: "list[str] | None" = None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION_RE.match(line)
+        if m:
+            lines = comps.setdefault(m.group(1), [])
+        elif lines is not None and line.startswith(" "):
+            lines.append(line)
+
+    def called(line):
+        for one, many in _CALLED_RE.findall(line):
+            yield from ([one] if one else
+                        [c.strip().lstrip("%") for c in many.split(",")])
+
+    stack = [m.group(1) for body in comps.values() for line in body
+             if " while(" in line
+             for m in re.finditer(r"body=%?([\w.\-]+)", line)]
+    seen: set[str] = set()
+    ops = []
+    while stack:
+        name = stack.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            stack.extend(called(line))
+            m = _ARRAY_INSTR_RE.match(line)
+            if m:
+                dims = tuple(int(d) for d in m.group(1).split(",") if d)
+                ops.append((m.group(2), dims))
+    return ops

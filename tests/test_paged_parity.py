@@ -5,7 +5,10 @@ gather through block tables) must agree with the dense per-slot vector
 decode step for every block size (1, non-power-of-two, 16) and any
 ragged ``cache_len`` / ``active`` pattern.  (Bit-exact *stream* identity
 is asserted under synchronous dispatch in tests/test_serving.py via the
-identity child; here we fuzz the step function directly.)
+identity child; here we fuzz the step function directly.)  Program-level
+parity: the stepper's paged chunk and megastep, whose layer scan carries
+the stacked pool, give the dense programs' tokens and logits bit for
+bit.
 
 Cache-level properties: prefix-shared blocks are refcounted and
 immutable — never freed while a holder remains, never writable, and the
@@ -21,6 +24,7 @@ from repro.configs import get_config
 from repro.models import build_model
 from repro.runtime.engine import ContinuousEngine, Request
 from repro.runtime.kv_cache import BlockKVCache
+from repro.runtime.stepper import Stepper
 
 TOL = dict(rtol=1e-5, atol=1e-6)
 
@@ -97,6 +101,66 @@ def test_paged_step_matches_dense_step_hypothesis():
         _run_parity("stablelm-3b", bs, steps, seed)
 
     run()
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "h2o-danube-3-4b",
+                                  "jamba-v0.1-52b"])
+def test_paged_programs_match_dense_bit_for_bit(arch):
+    """Ragged prompts through the chunked prefill, then three megasteps
+    with rows finishing at different steps: every first token, sampled
+    token and executed-row mask of the paged programs equals the dense
+    programs', and a decode step on the caches they leave gives the same
+    logits, bit for bit."""
+    api, params = _api(arch)
+    cfg = api.cfg
+    B, bs, bps, C, N = 3, 4, 8, 4, 5
+    rng = np.random.default_rng(7)
+    tables = rng.permutation(B * bps).astype(np.int32).reshape(B, bps)
+    st = Stepper(api)
+    dense = api.init_caches(B, bps * bs, jnp.dtype(cfg.dtype))
+    paged = api.init_paged_caches(B, B * bps, bs, jnp.dtype(cfg.dtype))
+    prompt_len = np.array([7, 3, 10], np.int32)
+    prompts = rng.integers(0, cfg.vocab_size, (B, 12)).astype(np.int32)
+
+    lens = np.zeros(B, np.int32)
+    while (lens < prompt_len).any():
+        n_valid = np.minimum(prompt_len - lens, C).astype(np.int32)
+        toks = np.zeros((B, C), np.int32)
+        for b in range(B):
+            toks[b, :n_valid[b]] = prompts[b, lens[b]:lens[b] + n_valid[b]]
+        dense, new_lens, first, _ = st.prefill_chunk(params, dense, toks,
+                                                     lens, n_valid)
+        paged, new_lens_p, first_p, _ = st.prefill_chunk(
+            params, paged, toks, lens, n_valid, block_tables=tables)
+        np.testing.assert_array_equal(new_lens, new_lens_p)
+        np.testing.assert_array_equal(first, first_p)
+        lens = np.asarray(new_lens)
+
+    toks, active = np.asarray(first), np.ones(B, bool)
+    budget = np.array([9, 4, 12], np.int32)
+    eos = np.full(B, -1, np.int32)
+    forced, n_forced = np.zeros((B, N), np.int32), np.zeros(B, np.int32)
+    for _ in range(3):
+        out = st.megastep(params, dense, toks, lens, active, budget, forced,
+                          n_forced, eos)
+        out_p = st.megastep(params, paged, toks, lens, active, budget,
+                            forced, n_forced, eos, block_tables=tables)
+        for a, b in zip(out[:2], out_p[:2]):        # tokens, executed rows
+            np.testing.assert_array_equal(a, b)
+        dense, paged = out[3], out_p[3]
+        executed = np.asarray(out[1]).sum(0).astype(np.int32)
+        lens, toks = lens + executed, np.asarray(out[0])[-1]
+        budget = budget - executed
+        active = budget > 0
+    assert not active.any()                 # every row finished
+
+    batch = {"tokens": jnp.asarray(toks[:, None]),
+             "cache_len": jnp.asarray(lens), "active": jnp.ones(B, bool)}
+    decode = jax.jit(api.decode_fn)
+    logits, _ = decode(params, dense, batch)
+    logits_p, _ = decode(params, paged,
+                         dict(batch, block_tables=jnp.asarray(tables)))
+    np.testing.assert_array_equal(logits, logits_p)
 
 
 # --------------------------------------------------------------------------

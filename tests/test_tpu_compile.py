@@ -4,8 +4,8 @@ The TPU's compiler is installed with JAX, so it can refuse here what
 interpret mode accepts: a tile that is not aligned, a kernel that wants
 more fast memory than it may use, a step that does not fit the chip's
 HBM.  These tests compile the serving path's programs at stablelm-3b's
-published width (cut to 2 layers) and the grouped GEMM at the shapes the
-schedule compiler gives it.  The topology is described inside a fixture,
+and h2o-danube-3-4b's published widths (cut to 2 layers) and the grouped
+GEMM at the shapes the schedule compiler gives it.  The topology is described inside a fixture,
 never at import: only one process at a time may load the TPU's library,
 and every test worker imports this file.
 """
@@ -25,6 +25,7 @@ from repro.configs import get_config
 from repro.kernels.branch_matmul.ops import grouped_branch_matmul
 from repro.models import build_model
 from repro.runtime.stepper import Stepper
+from repro.utils.hlo import loop_body_ops
 
 #: What XLA lets one program use of a v5e chip's 16 GiB of HBM.
 V5E_HBM = 15.75 * 2 ** 30
@@ -86,11 +87,10 @@ def test_grouped_branch_matmul_compiles_for_v5e(one_chip, shape, dtype,
 
 # -- serving steps -----------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def serving_shapes(one_chip):
-    """(stepper, params, caches, arg factories) for stablelm-3b at full
+def _serving_shapes(arch, one_chip):
+    """(stepper, params, caches, arg factories) for ``arch`` at full
     width, 2 layers, bf16 weights, on the smoke's paged pool."""
-    cfg = dataclasses.replace(get_config("stablelm-3b"), num_layers=2)
+    cfg = dataclasses.replace(get_config(arch), num_layers=2)
     api = build_model(cfg)
     dt = jnp.dtype(cfg.dtype)
 
@@ -122,6 +122,11 @@ def serving_shapes(one_chip):
     return Stepper(api), params, caches, args
 
 
+@pytest.fixture(scope="module")
+def serving_shapes(one_chip):
+    return _serving_shapes("stablelm-3b", one_chip)
+
+
 @pytest.mark.parametrize("step", ["decode", "megastep", "prefill_chunk"])
 def test_serving_step_fits_v5e(serving_shapes, step):
     stepper, params, caches, args = serving_shapes
@@ -131,3 +136,34 @@ def test_serving_step_fits_v5e(serving_shapes, step):
                   for a in jax.tree.leaves(params))
     assert compiled.memory_analysis().argument_size_in_bytes > weights
     assert _hbm_bytes(compiled) < V5E_HBM
+
+
+#: ``memory_analysis().temp_size_in_bytes`` of the paged programs at the
+#: shapes above when the layer scan took each layer's pool as ``xs`` and
+#: gave it back as ``ys`` (compiled for the described v5e with jax 0.9.0
+#: and libtpu 0.0.34): the ceiling for the carried pool.
+XS_POOL_TEMP_BYTES = {
+    ("stablelm-3b", "megastep"): 105_769_984,
+    ("stablelm-3b", "prefill_chunk"): 105_506_816,
+    ("h2o-danube-3-4b", "megastep"): 1_981_440,
+    ("h2o-danube-3-4b", "prefill_chunk"): 1_782_784,
+}
+
+
+@pytest.mark.parametrize("step", ["megastep", "prefill_chunk"])
+@pytest.mark.parametrize("arch", ["stablelm-3b", "h2o-danube-3-4b"])
+def test_layer_scan_moves_no_whole_pool(one_chip, arch, step):
+    """The stacked KV pool rides in the layer scan's carry: no loop body
+    copies, slices or writes back the pool or one layer's pool; each
+    step writes its token in place and gathers through the table."""
+    stepper, params, caches, args = _serving_shapes(arch, one_chip)
+    fn, *rest = args[step](stepper)
+    compiled = fn.lower(params, caches, *rest).compile()
+    pool = caches["period"][0]["k_pool"].shape
+    layer_pool = (1,) + pool[1:]
+    moved = [(op, dims) for op, dims in loop_body_ops(compiled.as_text())
+             if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+             and dims in (pool, pool[1:], layer_pool)]
+    assert not moved, moved
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= XS_POOL_TEMP_BYTES[arch, step]

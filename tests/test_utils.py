@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.utils.hlo import (bf16_convert_artifact_bytes, collective_bytes,
-                             collective_counts)
+                             collective_counts, loop_body_ops)
 from repro.utils.roofline import (HBM_BW, ICI_BW, PEAK_FLOPS,
                                   model_flops_estimate, roofline)
 from repro.utils.sharding import spec_for
@@ -53,6 +53,22 @@ def test_convert_artifact_wrapped_dedup():
 
 
 # -- roofline ---------------------------------------------------------------
+
+def test_loop_body_ops_reads_only_loop_bodies():
+    """What a scan's body runs is listed, fused or not; what runs once
+    outside the loop is not."""
+    def f(x, y):
+        z = jax.lax.scan(lambda c, _: (c * 3.0, None), x, None,
+                         length=4)[0]
+        return z, y[::2]
+
+    text = jax.jit(f).lower(jnp.ones((4, 8)), jnp.ones((6, 5))) \
+        .compile().as_text()
+    ops = loop_body_ops(text)
+    assert ("multiply", (4, 8)) in ops
+    assert not any(dims == (3, 5) for _, dims in ops)
+    assert loop_body_ops(HLO_SAMPLE) == []
+
 
 def test_roofline_terms_and_dominant():
     rl = roofline(flops_per_device=197e12, bytes_per_device=819e9,
