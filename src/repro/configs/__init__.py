@@ -16,10 +16,12 @@ from .dbrx_132b import CONFIG as DBRX_132B
 from .kimi_k2_1t_a32b import CONFIG as KIMI_K2_1T_A32B
 from .mamba2_370m import CONFIG as MAMBA2_370M
 from .h2o_danube_3_4b import CONFIG as H2O_DANUBE_3_4B
+from .granite_4_0_h_small import CONFIG as GRANITE_4_0_H_SMALL
 
 ARCHS = {c.name: c for c in (
     WHISPER_TINY, QWEN2_VL_2B, JAMBA_V0_1_52B, QWEN2_72B, YI_34B,
-    STABLELM_3B, DBRX_132B, KIMI_K2_1T_A32B, MAMBA2_370M, H2O_DANUBE_3_4B)}
+    STABLELM_3B, DBRX_132B, KIMI_K2_1T_A32B, MAMBA2_370M, H2O_DANUBE_3_4B,
+    GRANITE_4_0_H_SMALL)}
 
 
 def get_config(arch_id: str) -> ModelConfig:

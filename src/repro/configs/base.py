@@ -17,11 +17,16 @@ from dataclasses import dataclass, field
 class MoEConfig:
     num_experts: int = 0
     num_experts_per_tok: int = 0
-    d_ff_expert: int = 0           # per-expert hidden dim
+    d_ff_expert: int = 0           # per-expert hidden dim; 0 -> d_ff
     layer_freq: int = 1            # every n-th block is MoE (jamba: 2)
     layer_offset: int = 0          # first MoE block index
     capacity_factor: float = 1.25  # EP dispatch capacity
     num_shared_experts: int = 0    # always-active shared expert (Kimi K2)
+    shared_d_ff: int = 0           # its width; 0 -> d_ff_expert * count
+    # expert parallelism on one chip: this chip holds the routed experts
+    # [0, num_local_experts); the router still scores all num_experts
+    # (0 -> every expert held)
+    num_local_experts: int = 0
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01  # load-balance loss (Switch-style)
 
@@ -52,6 +57,8 @@ class ModelConfig:
     sliding_window: int = 0        # 0 -> full attention
     rope_theta: float = 1e4
     mrope_sections: tuple = ()     # e.g. (16, 24, 24) for Qwen2-VL M-RoPE
+    nope: bool = False             # no position embedding (Granite 4.0-H)
+    attn_scale: float = 0.0        # softmax scale; 0 -> 1 / sqrt(head_dim)
     # mixture of experts
     moe: MoEConfig = field(default_factory=MoEConfig)
     # state-space (mamba2 / jamba)
@@ -68,6 +75,13 @@ class ModelConfig:
     num_frontend_tokens: int = 0   # vision/audio tokens prepended at prefill
     # norms / activations / misc
     norm_type: str = "rmsnorm"     # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6
+    # muP-style scalars (Granite): x = embedding_multiplier * E[tok];
+    # each residual add scales its branch by residual_multiplier; the
+    # logits are divided by logits_scaling.  1.0 -> the op is not built
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     act: str = "silu"              # silu (SwiGLU) | gelu (plain MLP)
     tie_embeddings: bool = False
     max_position: int = 1 << 20
@@ -83,6 +97,17 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.num_heads, 1)
+
+    def expert_width(self) -> int:
+        return self.moe.d_ff_expert or self.d_ff
+
+    def shared_expert_width(self) -> int:
+        m = self.moe
+        return m.shared_d_ff or self.expert_width() * m.num_shared_experts
+
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.moe.num_local_experts or self.moe.num_experts
 
     def is_moe_layer(self, i: int) -> bool:
         m = self.moe
@@ -111,8 +136,9 @@ class ModelConfig:
                 n += d * (2 * di + 2 * self.ssm.n_groups * self.ssm.d_state
                           + di // self.ssm.head_dim) + di * d
             if self.is_moe_layer(i):
-                n += (self.moe.num_experts * 3 * d * self.moe.d_ff_expert
-                      + d * self.moe.num_experts)
+                n += (self.held_experts() * 3 * d * self.expert_width()
+                      + d * self.moe.num_experts
+                      + 3 * d * self.shared_expert_width())
             elif self.d_ff:
                 mult = 3 if self.act == "silu" else 2
                 n += mult * d * self.d_ff
@@ -130,10 +156,11 @@ class ModelConfig:
         full = self.param_count()
         moe_layers = sum(1 for i in range(self.num_layers)
                          if self.is_moe_layer(i))
-        all_exp = moe_layers * self.moe.num_experts * 3 * self.d_model \
-            * self.moe.d_ff_expert
-        act_exp = moe_layers * self.moe.num_experts_per_tok * 3 \
-            * self.d_model * self.moe.d_ff_expert
+        all_exp = moe_layers * self.held_experts() * 3 * self.d_model \
+            * self.expert_width()
+        act_exp = moe_layers * min(self.moe.num_experts_per_tok,
+                                   self.held_experts()) * 3 \
+            * self.d_model * self.expert_width()
         return full - all_exp + act_exp
 
     def structural(self) -> "ModelConfig":
@@ -145,7 +172,10 @@ class ModelConfig:
         kv = self.num_kv_heads
         moe = self.moe
         if moe.num_experts:
-            moe = dataclasses.replace(moe, d_ff_expert=32)
+            moe = dataclasses.replace(
+                moe, d_ff_expert=32,
+                shared_d_ff=32 * moe.num_shared_experts
+                if moe.shared_d_ff else 0)
         ssm = self.ssm
         if ssm.d_state:
             ssm = dataclasses.replace(ssm, d_state=8, head_dim=8, chunk=8)
@@ -176,7 +206,9 @@ class ModelConfig:
             moe = dataclasses.replace(
                 moe, num_experts=min(4, moe.num_experts),
                 num_experts_per_tok=min(2, moe.num_experts_per_tok),
-                d_ff_expert=min(128, moe.d_ff_expert),
+                d_ff_expert=min(128, self.expert_width()),
+                shared_d_ff=min(256, moe.shared_d_ff),
+                num_local_experts=min(2, moe.num_local_experts),
                 layer_freq=1, layer_offset=0)
         ssm = self.ssm
         if ssm.d_state:

@@ -41,7 +41,8 @@ def init_attention(key, cfg, d_model=None):
 
 
 def qkv_project(params, cfg, x, positions=None, positions3=None):
-    """x: (B, S, d) -> q (B,S,H,D), k/v (B,S,K,D) with RoPE applied."""
+    """x: (B, S, d) -> q (B,S,H,D), k/v (B,S,K,D) with RoPE applied
+    (none where ``cfg.nope``)."""
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim()
     H, K = cfg.num_heads, cfg.num_kv_heads
@@ -57,6 +58,8 @@ def qkv_project(params, cfg, x, positions=None, positions3=None):
     k = proj(params["wk"], params.get("bk"), K)
     v = proj(params["wv"], params.get("bv"), K)
 
+    if cfg.nope:
+        return q, k, v
     if cfg.mrope_sections:
         assert positions3 is not None, "M-RoPE needs 3-stream positions"
         q = apply_mrope(q, positions3, cfg.mrope_sections, cfg.rope_theta)
@@ -67,13 +70,20 @@ def qkv_project(params, cfg, x, positions=None, positions3=None):
     return q, k, v
 
 
-def _gqa_scores(q, k):
-    """(B,S,H,D) x (B,T,K,D) -> (B,K,G,S,T) with G = H // K."""
+def softmax_scale(cfg):
+    """The configured softmax scale, or None for ``1 / sqrt(head_dim)``."""
+    return cfg.attn_scale or None
+
+
+def _gqa_scores(q, k, scale=None):
+    """(B,S,H,D) x (B,T,K,D) -> (B,K,G,S,T) with G = H // K, times
+    ``scale`` (None: divided by sqrt(D))."""
     B, S, H, D = q.shape
     K = k.shape[2]
     G = H // K
     qg = q.reshape(B, S, K, G, D)
-    return jnp.einsum("bskgd,btkd->bkgst", qg, k) / np.sqrt(D)
+    s = jnp.einsum("bskgd,btkd->bkgst", qg, k)
+    return s / np.sqrt(D) if scale is None else s * scale
 
 
 def _gqa_context(p, v):
@@ -94,9 +104,9 @@ def causal_mask(S: int, T: int, q_offset=0, window: int = 0):
     return m
 
 
-def attend(q, k, v, mask=None):
+def attend(q, k, v, mask=None, scale=None):
     """Masked softmax attention with GQA grouping; fp32 softmax."""
-    s = _gqa_scores(q, k).astype(jnp.float32)
+    s = _gqa_scores(q, k, scale).astype(jnp.float32)
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -104,7 +114,7 @@ def attend(q, k, v, mask=None):
 
 
 def attend_chunked(q, k, v, *, causal=True, window=0, q_offset=0,
-                   chunk_q=512, chunk_k=1024):
+                   chunk_q=512, chunk_k=1024, scale=None):
     """Exact chunked attention (online softmax over tiles).
 
     Same contract as :func:`attend` with a causal/window mask, but the
@@ -120,7 +130,8 @@ def attend_chunked(q, k, v, *, causal=True, window=0, q_offset=0,
     ck = min(chunk_k, T)
     assert S % cq == 0 and T % ck == 0, (S, T, cq, ck)
     nq, nk = S // cq, T // ck
-    scale = 1.0 / np.sqrt(D)
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
 
     qs = jnp.moveaxis(q.reshape(B, nq, cq, K, G, D), 1, 0)
     ks = jnp.moveaxis(k.reshape(B, nk, ck, K, D), 1, 0)
@@ -182,10 +193,11 @@ def self_attention(params, cfg, x, positions=None, positions3=None,
             and S % runtime_flags.chunk_k == 0):
         ctx = attend_chunked(q, k, v, causal=True, window=w,
                              chunk_q=runtime_flags.chunk_q,
-                             chunk_k=runtime_flags.chunk_k)
+                             chunk_k=runtime_flags.chunk_k,
+                             scale=softmax_scale(cfg))
     else:
         mask = causal_mask(S, S, 0, w) if causal else None
-        ctx = attend(q, k, v, mask)
+        ctx = attend(q, k, v, mask, softmax_scale(cfg))
     B = x.shape[0]
     out = jnp.einsum("bsf,fd->bsd",
                      ctx.reshape(B, S, -1), params["wo"].astype(x.dtype))
@@ -339,7 +351,8 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
     if w > 0:
         valid &= pos > cache_len - w
     mask = valid[None, :]                                 # (S=1, T)
-    ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask)
+    ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask,
+                 softmax_scale(cfg))
     out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, 1, -1),
                      params["wo"].astype(x.dtype))
     return out, {"k": k, "v": v, "pos": pos}
@@ -377,7 +390,8 @@ def _decode_step_attention_vec(params, cfg, x, cache, cache_len,
     if w > 0:
         valid &= t > positions - w
     mask = valid[:, None, None, None, :]                  # (B,1,1,S=1,T)
-    ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask)
+    ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask,
+                 softmax_scale(cfg))
     out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, 1, -1),
                      params["wo"].astype(x.dtype))
     return out, {"k": k, "v": v, "pos": cache["pos"]}
@@ -444,7 +458,8 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
     if w > 0:
         valid &= t > positions - w
     mask = valid[:, None, None, None, :]                  # (B,1,1,S=1,T)
-    ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask)
+    ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask,
+                 softmax_scale(cfg))
     out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, 1, -1),
                      params["wo"].astype(x.dtype))
     return out, {"k_pool": pool_k, "v_pool": pool_v}

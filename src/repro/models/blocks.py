@@ -72,18 +72,26 @@ def init_block(key, cfg, kind):
     return p
 
 
+def _residual(cfg, x, y):
+    """``x + y``, the branch ``y`` scaled by ``cfg.residual_multiplier``
+    where one is set."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 def _channel_mix(params, cfg, x, kind, moe_impl, mesh):
     """The block's channel mix and its residual add, named ``norm`` and
     ``mlp`` (dense or MoE) in the program's name stack."""
     channel = kind[1]
     if channel == "none":
         return x, 0.0
-    norm = make_norm(cfg.norm_type)
+    norm = make_norm(cfg.norm_type, cfg.rms_norm_eps)
     with jax.named_scope("norm"):
         h = norm(params["norm2"], x)
     with jax.named_scope("mlp"):
         if channel == "dense":
-            return x + mlp(params["mlp"], h, cfg.act), 0.0
+            return _residual(cfg, x, mlp(params["mlp"], h, cfg.act)), 0.0
         B, S, d = h.shape
         flat = h.reshape(B * S, d)
         if moe_impl == "dense":
@@ -92,23 +100,24 @@ def _channel_mix(params, cfg, x, kind, moe_impl, mesh):
             y, aux = moe_ep(params["moe"], cfg, flat, mesh)
         else:
             y, aux = moe_ragged(params["moe"], cfg, flat)
-        return x + y.reshape(B, S, d), aux
+        return _residual(cfg, x, y.reshape(B, S, d)), aux
 
 
 def apply_block(params, cfg, x, kind, positions=None, positions3=None,
                 moe_impl="ragged", mesh=None, window=None):
     """Full-sequence (train / prefill) block.  x: (B, S, d)."""
     mixer, _ = kind
-    norm = make_norm(cfg.norm_type)
+    norm = make_norm(cfg.norm_type, cfg.rms_norm_eps)
     with jax.named_scope("norm"):
         h = norm(params["norm1"], x)
     if mixer == "attn":
         with jax.named_scope("attention"):
-            x = x + self_attention(params["attn"], cfg, h, positions,
-                                   positions3, causal=True, window=window)
+            x = _residual(cfg, x, self_attention(
+                params["attn"], cfg, h, positions, positions3, causal=True,
+                window=window))
     else:
         with jax.named_scope("ssm"):
-            x = x + mamba_block(params["mamba"], cfg, h)
+            x = _residual(cfg, x, mamba_block(params["mamba"], cfg, h))
     x = shard_batch_seq(x)
     x, aux = _channel_mix(params, cfg, x, kind, moe_impl, mesh)
     return shard_batch_seq(x), aux
@@ -156,12 +165,13 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
     ``jnp.where`` below).
 
     Each part and its residual add runs under a ``jax.named_scope``
-    (``norm``, ``attention`` or ``ssm``, then ``norm`` and ``mlp``), so
-    every operation of the served step names the part it belongs to in
-    its op metadata.
+    (``norm``, ``attention`` or ``ssm``, then ``norm`` and ``mlp``; an
+    MoE's ``mlp`` holds ``router``, ``experts`` and ``shared_expert``),
+    so every operation of the served step names the part it belongs to
+    in its op metadata.
     """
     mixer, _ = kind
-    norm = make_norm(cfg.norm_type)
+    norm = make_norm(cfg.norm_type, cfg.rms_norm_eps)
     with jax.named_scope("norm"):
         h = norm(params["norm1"], x)
     if mixer == "attn":
@@ -171,7 +181,7 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
                                              active=active,
                                              block_tables=block_tables,
                                              layer=layer)
-            x = x + y
+            x = _residual(cfg, x, y)
     else:
         with jax.named_scope("ssm"):
             y, new_cache = mamba_decode_step(params["mamba"], cfg, h,
@@ -184,7 +194,7 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
                     new_cache, cache)
             else:
                 cache = new_cache
-            x = x + y
+            x = _residual(cfg, x, y)
     x = shard_decode(x)
     x, _aux = _channel_mix(params, cfg, x, kind, moe_impl, mesh)
     return shard_decode(x), cache

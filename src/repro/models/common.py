@@ -51,9 +51,11 @@ def layer_norm(params, x, eps=1e-5):
             + params["bias"].astype(jnp.float32)).astype(dt)
 
 
-def make_norm(norm_type: str):
+def make_norm(norm_type: str, eps: float = 1e-6):
+    """The norm of ``norm_type``; ``eps`` is RMSNorm's (LayerNorm keeps
+    its own 1e-5)."""
     if norm_type == "rmsnorm":
-        return lambda p, x: rms_norm(p["scale"], x)
+        return lambda p, x: rms_norm(p["scale"], x, eps)
     if norm_type == "layernorm":
         return layer_norm
     raise ValueError(norm_type)

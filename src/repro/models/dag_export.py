@@ -287,8 +287,8 @@ def _export_moe(b, cfg, mp, h, layer_i, B, S, fc=None):
     heterogeneous mix Parallax targets."""
     fc = fc or cfg
     m = cfg.moe
-    d, ff = cfg.d_model, m.d_ff_expert
-    dF, ffF = fc.d_model, fc.moe.d_ff_expert
+    d, ff = cfg.d_model, cfg.expert_width()
+    dF, ffF = fc.d_model, fc.expert_width()
     E, k = m.num_experts, m.num_experts_per_tok
     router_w = jnp.asarray(_np(mp["router"]))
 

@@ -2,8 +2,9 @@
 
 Three execution paths share one parameter layout:
 
-* ``moe_dense`` — every expert processes every token, gate-weighted
-  combine.  O(E) FLOPs; only for tiny smoke configs (E <= 4).
+* ``moe_dense`` — every held expert processes every token, gate-weighted
+  combine.  O(E) FLOPs: for tiny smoke configs (E <= 4) and for a chip
+  that holds no more experts than a token is routed to.
 * ``moe_ragged`` — single-shard *dropless* compute: token copies sorted by
   expert id, grouped GEMM via ``jax.lax.ragged_dot``.  This is the direct
   Parallax realization: the E experts are the balanced parallel branches
@@ -17,6 +18,12 @@ Parameters:
     router: (d, E)
     w_gate / w_up: (E, d, f)    w_down: (E, f, d)
     shared expert (optional): plain SwiGLU MLP always active (Kimi K2).
+
+A config may hold a share of the routed experts (the first ``MoEConfig.
+num_local_experts``): the router still scores all of them, the expert weights are the held ones' only, and the
+single-shard paths compute the share's part of the layer, with no
+exchange and no stand-in for the rest.  The parts run under the
+``router``, ``experts`` and ``shared_expert`` named scopes.
 """
 
 from __future__ import annotations
@@ -30,17 +37,20 @@ from .mlp import init_mlp, mlp
 
 
 def init_moe(key, cfg):
+    """The router scores every expert; the expert weights are the held
+    ones' only (``cfg.held_experts()``, all unless the config holds a
+    share)."""
     m = cfg.moe
-    d, f, E = cfg.d_model, m.d_ff_expert, m.num_experts
+    d, f, E = cfg.d_model, cfg.expert_width(), cfg.held_experts()
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], (d, E)),
+        "router": dense_init(ks[0], (d, m.num_experts)),
         "w_gate": dense_init(ks[1], (E, d, f), in_axis=-2),
         "w_up": dense_init(ks[2], (E, d, f), in_axis=-2),
         "w_down": dense_init(ks[3], (E, f, d), in_axis=-2),
     }
     if m.num_shared_experts:
-        p["shared"] = init_mlp(ks[4], d, f * m.num_shared_experts, "silu")
+        p["shared"] = init_mlp(ks[4], d, cfg.shared_expert_width(), "silu")
     return p
 
 
@@ -68,42 +78,68 @@ def _expert_ffn_ragged(params, xs, group_sizes, dtype):
     return jax.lax.ragged_dot(h, params["w_down"].astype(dtype), group_sizes)
 
 
+def _shared_expert(params, x, y):
+    if "shared" not in params:
+        return y
+    with jax.named_scope("shared_expert"):
+        return y + mlp(params["shared"], x, "silu")
+
+
 def moe_ragged(params, cfg, x):
-    """Dropless single-shard MoE.  x: (T, d) -> (y (T, d), aux)."""
+    """Dropless single-shard MoE.  x: (T, d) -> (y (T, d), aux).
+
+    Where the config holds a share of the experts, the assignments to
+    absent experts sort last, past every held group, and give nothing:
+    ``y`` is this share's part of the layer (plus the shared expert)."""
     m = cfg.moe
     T, d = x.shape
     k = m.num_experts_per_tok
-    w, idx, aux = route(params, cfg, x)
+    E = cfg.held_experts()
+    with jax.named_scope("router"):
+        w, idx, aux = route(params, cfg, x)
 
-    flat_e = idx.reshape(-1)                              # (T*k,)
-    order = jnp.argsort(flat_e)
-    token_of = order // k                                 # source token
-    xs = x[token_of]                                      # (T*k, d) sorted
-    group_sizes = jnp.bincount(flat_e, length=m.num_experts)
-    ys = _expert_ffn_ragged(params, xs, group_sizes, x.dtype)
-    # un-sort and gate-weighted combine
-    contrib = ys * w.reshape(-1)[order][:, None]
-    y = jnp.zeros_like(x).at[token_of].add(contrib)
-    if "shared" in params:
-        y = y + mlp(params["shared"], x, "silu")
-    return y, aux
+    share = E < m.num_experts
+    with jax.named_scope("experts"):
+        flat_e = idx.reshape(-1)                          # (T*k,)
+        if share:            # absent experts' choices: group E, last
+            flat_e = jnp.minimum(flat_e, E)
+        order = jnp.argsort(flat_e)
+        token_of = order // k                             # source token
+        xs = x[token_of]                                  # (T*k, d) sorted
+        group_sizes = (jnp.bincount(flat_e, length=E + 1)[:E] if share
+                       else jnp.bincount(flat_e, length=E))
+        ys = _expert_ffn_ragged(params, xs, group_sizes, x.dtype)
+        # un-sort and gate-weighted combine
+        contrib = ys * w.reshape(-1)[order][:, None]
+        if share:            # ragged_dot leaves rows past the groups open
+            contrib = jnp.where((flat_e[order] < E)[:, None], contrib, 0)
+        y = jnp.zeros_like(x).at[token_of].add(contrib)
+    return _shared_expert(params, x, y), aux
 
 
 def moe_dense(params, cfg, x):
-    """All-experts einsum (smoke-test oracle).  x: (T, d)."""
+    """Every held expert on every token, gate-weighted combine (the
+    smoke-test oracle, and the served path where a chip holds no more
+    experts than a token is routed to).  x: (T, d).  Absent experts of
+    a share get no gate."""
     m = cfg.moe
-    w, idx, aux = route(params, cfg, x)
+    E = cfg.held_experts()
+    with jax.named_scope("router"):
+        w, idx, aux = route(params, cfg, x)
     dt = x.dtype
-    g = jnp.einsum("td,edf->tef", x, params["w_gate"].astype(dt))
-    u = jnp.einsum("td,edf->tef", x, params["w_up"].astype(dt))
-    h = jax.nn.silu(g) * u
-    ys = jnp.einsum("tef,efd->ted", h, params["w_down"].astype(dt))
-    gates = jnp.zeros((x.shape[0], m.num_experts), dt)
-    gates = gates.at[jnp.arange(x.shape[0])[:, None], idx].add(w)
-    y = jnp.einsum("ted,te->td", ys, gates)
-    if "shared" in params:
-        y = y + mlp(params["shared"], x, "silu")
-    return y, aux
+    with jax.named_scope("experts"):
+        g = jnp.einsum("td,edf->tef", x, params["w_gate"].astype(dt))
+        u = jnp.einsum("td,edf->tef", x, params["w_up"].astype(dt))
+        h = jax.nn.silu(g) * u
+        ys = jnp.einsum("tef,efd->ted", h, params["w_down"].astype(dt))
+        if E < m.num_experts:
+            hot = jax.nn.one_hot(idx, E, dtype=dt)
+            gates = jnp.einsum("tk,tke->te", w, hot)
+        else:
+            gates = jnp.zeros((x.shape[0], E), dt)
+            gates = gates.at[jnp.arange(x.shape[0])[:, None], idx].add(w)
+        y = jnp.einsum("ted,te->td", ys, gates)
+    return _shared_expert(params, x, y), aux
 
 
 # --------------------------------------------------------------------------
@@ -158,7 +194,7 @@ def _moe_ep_replicated(params, cfg, x, mesh, axis: str = "model"):
     n_data = 1
     for a in data_axes:
         n_data *= mesh.shape[a]
-    f_sharded = data_axes and m.d_ff_expert % n_data == 0
+    f_sharded = data_axes and cfg.expert_width() % n_data == 0
 
     def local_moe(router, w_gate, w_up, w_down, x_loc):
         # w_gate/w_up: (E_local, d, f_loc); w_down: (E_local, f_loc, d)

@@ -28,7 +28,7 @@ def moe_branch_matmul(params, cfg, x, *, capacity_factor: float = 2.0,
     m = cfg.moe
     T, d = x.shape
     E, k = m.num_experts, m.num_experts_per_tok
-    f = m.d_ff_expert
+    f = cfg.expert_width()
     w, idx, aux = route(params, cfg, x)
 
     # capacity bucketing: position of each (token, choice) in its expert

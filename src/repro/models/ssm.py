@@ -217,7 +217,7 @@ def mamba_block(params, cfg, x, use_chunked=True):
     C = C.reshape(b, S, s.n_groups, s.d_state)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params["dt_bias"][None, None, :])
-    A = -jnp.exp(params["A_log"])
+    A = -jnp.exp(params["A_log"].astype(jnp.float32))
 
     from . import runtime_flags
     if runtime_flags.shard_ssm_heads:
@@ -234,7 +234,8 @@ def mamba_block(params, cfg, x, use_chunked=True):
     y = y + params["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(b, S, d_inner).astype(dt_p)
     # gated RMSNorm (Mamba2): norm(y * silu(z))
-    y = rms_norm(params["norm_scale"], y * jax.nn.silu(z))
+    y = rms_norm(params["norm_scale"], y * jax.nn.silu(z),
+                 cfg.rms_norm_eps)
     return jnp.einsum("bsf,fd->bsd", y, params["out_proj"].astype(dt_p))
 
 
@@ -281,12 +282,13 @@ def mamba_decode_step(params, cfg, x, cache):
     C = C.reshape(b, s.n_groups, s.d_state)
     dtv = jax.nn.softplus(dt[:, 0].astype(jnp.float32)
                           + params["dt_bias"][None, :])
-    A = -jnp.exp(params["A_log"])
+    A = -jnp.exp(params["A_log"].astype(jnp.float32))
     y, state = ssd_decode_step(cache["state"], xs.astype(jnp.float32),
                                dtv, A, B.astype(jnp.float32),
                                C.astype(jnp.float32))
     y = y + params["D"][None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(b, 1, d_inner).astype(dt_p)
-    y = rms_norm(params["norm_scale"], y * jax.nn.silu(z))
+    y = rms_norm(params["norm_scale"], y * jax.nn.silu(z),
+                 cfg.rms_norm_eps)
     y = jnp.einsum("bsf,fd->bsd", y, params["out_proj"].astype(dt_p))
     return y, {"state": state, "conv": new_conv}

@@ -60,11 +60,20 @@ def init_lm(key, cfg):
     return params
 
 
+def _embed(params, cfg, tokens):
+    """The token embeddings, times ``cfg.embedding_multiplier`` where one
+    is set."""
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def embed_tokens(params, cfg, tokens, frontend_embeds=None):
     """tokens: (B, S_txt) int32 -> (B, S, d); frontend embeddings (vision
     patches / audio frames, already projected by the stub frontend) are
     prepended when present."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    x = _embed(params, cfg, tokens)
     if frontend_embeds is not None:
         x = jnp.concatenate([frontend_embeds.astype(cfg.dtype), x], axis=1)
     return shard_batch_seq(x)
@@ -102,7 +111,7 @@ def forward_lm(params, cfg, tokens, frontend_embeds=None, positions3=None,
             body, (x, jnp.float32(aux_total)), tuple(params["period"]),
             **runtime_flags.scan_kwargs())
 
-    norm = make_norm(cfg.norm_type)
+    norm = make_norm(cfg.norm_type, cfg.rms_norm_eps)
     return norm(params["final_norm"], x), aux_total
 
 
@@ -200,7 +209,7 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     """
     pattern, prefix_len, period, n_rep = structure(cfg)
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]  # (B, 1, d)
+        x = _embed(params, cfg, tokens)                # (B, 1, d)
 
     new_prefix = []
     for i in range(prefix_len):
@@ -241,7 +250,7 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
         new_period = [p if k else y
                       for p, y, k in zip(pools, ys, carried)]
 
-    norm = make_norm(cfg.norm_type)
+    norm = make_norm(cfg.norm_type, cfg.rms_norm_eps)
     with jax.named_scope("norm"):
         hidden = norm(params["final_norm"], x)
     with jax.named_scope("lm_head"):

@@ -1,10 +1,17 @@
-"""Output projection helpers (tied / untied vocab heads)."""
+"""Output projection helpers (tied / untied vocab heads); the logits are
+divided by ``cfg.logits_scaling`` where one is set."""
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from .sharding import maybe_shard, DP_AXES
+
+
+def _scaled(cfg, logits):
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def lm_logits(params, cfg, hidden):
@@ -16,7 +23,7 @@ def lm_logits(params, cfg, hidden):
     else:
         logits = jnp.einsum("bsd,dv->bsv", hidden,
                             params["lm_head"].astype(dt))
-    return maybe_shard(logits, DP_AXES, None, "model")
+    return maybe_shard(_scaled(cfg, logits), DP_AXES, None, "model")
 
 
 def logits_last_token(params, cfg, hidden):
@@ -28,4 +35,4 @@ def logits_last_token(params, cfg, hidden):
     else:
         logits = jnp.einsum("bd,dv->bv", last,
                             params["lm_head"].astype(dt))
-    return maybe_shard(logits, DP_AXES, "model")
+    return maybe_shard(_scaled(cfg, logits), DP_AXES, "model")

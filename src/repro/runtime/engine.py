@@ -781,6 +781,12 @@ class ContinuousEngine:
         # — all freshly written by the new tenant — so the reset dispatch
         # is skipped entirely (one dispatch saved per admission wave).
         self._needs_reset = self.kv.state_bytes > 0
+        self._m_state_resets = m.counter("engine.state_resets")
+        if self._needs_reset:
+            # compile the reset program with the engine, not inside the
+            # first admission wave: this call clears no row
+            self.caches = self.stepper.reset_rows(
+                self.caches, np.zeros(max_batch, bool))
 
     def submit(self, req: Request) -> bool:
         """Queue a request.  Malformed submissions raise; a full queue
@@ -1057,7 +1063,10 @@ class ContinuousEngine:
             return 0
         if self._needs_reset:
             self._m_dispatches.inc()
-            self.caches = self.stepper.reset_rows(self.caches, fresh)
+            self._m_state_resets.inc(int(fresh.sum()))
+            with self._rec.region("reset", iteration=self.iterations,
+                                  rows=int(fresh.sum())):
+                self.caches = self.stepper.reset_rows(self.caches, fresh)
         return int(fresh.sum())
 
     def _place(self, slot: int, seq: "_Seq", fresh: "np.ndarray") -> None:

@@ -37,9 +37,10 @@ run only at trace time), so ``chunk_traces`` / ``decode_traces`` observe
 XLA retraces directly; ``dispatches`` counts calls.
 
 The programs keep fixed names (:data:`DECODE_PROGRAM`,
-:data:`MEGASTEP_PROGRAM`, :data:`CHUNK_PROGRAM`), by which a profiler
-trace's reduction finds them, and the sampling tail of every step runs
-under the ``sample`` named scope (the model's parts carry their own).
+:data:`MEGASTEP_PROGRAM`, :data:`CHUNK_PROGRAM`, :data:`RESET_PROGRAM`),
+by which a profiler trace's reduction finds them, and the sampling tail
+of every step runs under the ``sample`` named scope (the model's parts
+carry their own).
 """
 
 from __future__ import annotations
@@ -54,17 +55,19 @@ from .telemetry import MetricsRegistry
 
 #: The served step programs' names, as XLA and the profiler show them
 #: (``jax.jit`` names a program ``jit_<function>``): the single decode
-#: step, the decode megastep's scan and the chunked prefill.  Profiler
-#: reductions find the programs by these names.
+#: step, the decode megastep's scan, the chunked prefill and the reset
+#: of new tenants' per-row state.  Profiler reductions find the
+#: programs by these names.
 DECODE_PROGRAM = "jit_step"
 MEGASTEP_PROGRAM = "jit_run"
 CHUNK_PROGRAM = "jit_run_chunk"
+RESET_PROGRAM = "jit_reset"
 
 
-def _program(fn, name: str):
+def _program(fn, name: str, **jit_kw):
     """``jax.jit`` of ``fn`` as the program named ``name``."""
     fn.__name__ = name.removeprefix("jit_")
-    return jax.jit(fn)
+    return jax.jit(fn, **jit_kw)
 
 
 def _device(x, dtype):
@@ -123,7 +126,11 @@ class Stepper:
                               MEGASTEP_PROGRAM)
         self._mega_paged = _program(self._make_megastep(paged=True),
                                     MEGASTEP_PROGRAM)
-        self._reset = jax.jit(self._make_reset())
+        # the reset writes the caches in place: its input is the
+        # engine's only reference, and a copy of every row's state would
+        # sit beside the chunk's and the megastep's own copies
+        self._reset = _program(self._make_reset(), RESET_PROGRAM,
+                               donate_argnums=0)
         # poisoned twins — identical math plus an in-trace NaN injection
         # (sampling.poison_logits) — are built lazily on the first
         # poisoned dispatch: a clean run never compiles injection code
@@ -403,6 +410,7 @@ class Stepper:
         """Zero every cache entry of rows with ``fresh[b]`` True — a new
         tenant must see exactly the state `init_caches` would give it
         (SSM state / conv windows are carried outside the masked KV
-        region, so stale tenants would otherwise leak through)."""
+        region, so stale tenants would otherwise leak through).
+        ``caches`` is donated: the caller keeps only what it returns."""
         self._m_dispatches.inc()
         return self._reset(caches, _device(fresh, bool))

@@ -77,18 +77,21 @@ import jax
 #: conversion, transfer, enqueue), ``sync`` (the blocking reads of a
 #: step's outputs) and ``reconcile``; ``dispatch`` and ``sync`` nest in
 #: their ``prefill_chunk`` / ``decode`` / ``megastep`` span, which
-#: nests in its ``iteration``.
+#: nests in its ``iteration``.  ``reset`` is the dispatch of the program
+#: that clears new tenants' per-row state (SSM state and conv windows;
+#: attention-only models never reset), inside ``schedule``.
 SPAN_KINDS = ("submit", "admit", "first_token", "schedule", "plan",
               "prefill_chunk", "decode", "megastep", "dispatch", "sync",
               "reconcile", "preempt", "spill", "restore", "stalled",
-              "fault", "complete", "iteration", "segment", "cache_evict")
+              "fault", "complete", "iteration", "segment", "cache_evict",
+              "reset")
 
 #: Kinds recorded with a duration (``ts`` + ``dur``); the rest are
 #: instantaneous points (``ts`` only).
 DURATION_KINDS = frozenset({"iteration", "schedule", "plan",
                             "prefill_chunk", "decode", "megastep",
                             "dispatch", "sync", "reconcile", "spill",
-                            "restore", "segment"})
+                            "restore", "segment", "reset"})
 POINT_KINDS = frozenset(k for k in SPAN_KINDS if k not in DURATION_KINDS)
 
 #: Kinds that always carry a ``request_id``.

@@ -403,8 +403,10 @@ def test_engine_span_taxonomy(model, shared_stepper):
     assert eng.stalls == len(stalled)
 
     # segment is hetero-only; cache_evict needs prefix_cache=True, and
-    # every engine above runs cache-off (test_prefix_cache.py covers it)
-    expected = set(SPAN_KINDS) - {"segment", "cache_evict"}
+    # every engine above runs cache-off (test_prefix_cache.py covers it);
+    # reset clears per-row SSM state, which an attention-only model has
+    # none of (test_granite_hybrid.py covers it)
+    expected = set(SPAN_KINDS) - {"segment", "cache_evict", "reset"}
     assert seen == expected
     # schema: every event stamped and shaped per its kind (the fault
     # run rides along so spill/restore/stalled are schema-checked too)
