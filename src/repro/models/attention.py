@@ -280,10 +280,14 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, dtype):
 def decode_step_attention(params, cfg, x, cache, cache_len,
                           positions3=None, window: int = 0, active=None,
                           block_tables=None, layer=None):
-    """One-token decode: x (B, 1, d) against cache k/v (B, slots, K, D).
+    """Decode step: x (B, S, d), S new tokens a row, against cache k/v
+    (B, slots, K, D) or a paged pool.
 
     ``cache_len`` is the number of tokens already generated/prefilled;
-    the new token has absolute position ``cache_len``.
+    row b's new tokens have absolute positions ``cache_len[b] + s``.
+    S > 1 (a prefill chunk as one block) needs vector ``cache_len`` and
+    takes ``active`` as (B, S), one flag a token; the tokens' K/V are
+    written first, and token s attends every position up to its own.
 
     *Scalar* ``cache_len`` (may be traced): every row is at the same
     position; the token is written to slot ``cache_len % slots`` (ring
@@ -312,9 +316,9 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
     reads and writes that layer's pool through it (see
     ``_decode_step_attention_paged``).
 
-    Returns ``(out (B,1,d), new_cache)``.
+    Returns ``(out (B,S,d), new_cache)``.
     """
-    B = x.shape[0]
+    B, S = x.shape[:2]
     cache_len = jnp.asarray(cache_len, jnp.int32)
     if "k_pool" in cache:
         if cache_len.ndim != 1 or block_tables is None:
@@ -328,10 +332,11 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
     if cache_len.ndim == 1:
         return _decode_step_attention_vec(params, cfg, x, cache, cache_len,
                                           positions3, window, active)
-    if active is not None:
+    if active is not None or S > 1:
         raise ValueError(
-            "per-row `active` gating requires vector cache_len (B,): the "
-            "scalar path writes every row's cache unconditionally")
+            "per-row `active` gating and multi-token steps require vector "
+            "cache_len (B,): the scalar path writes one token a row, "
+            "unconditionally")
     positions = jnp.broadcast_to(cache_len, (B, 1))
     if positions3 is None and cfg.mrope_sections:
         positions3 = jnp.broadcast_to(positions, (3, B, 1))
@@ -358,41 +363,66 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
     return out, {"k": k, "v": v, "pos": pos}
 
 
+def _token_positions(cache_len, S: int):
+    """(B, S) absolute positions of S new tokens a row: ``cache_len[b] +
+    s``."""
+    positions = cache_len[:, None]
+    if S > 1:
+        positions = positions + jnp.arange(S, dtype=jnp.int32)
+    return positions
+
+
+def _token_mask(t, positions, window: int):
+    """(B, 1, 1, S, T) attention mask of the new tokens at ``positions``
+    (B, S) over cache positions ``t`` (1, T): token s sees t <=
+    positions[b, s], and t > positions[b, s] - window where ``window`` >
+    0."""
+    if positions.shape[1] > 1:
+        t, positions = t[:, None], positions[:, :, None]  # (1,1,T), (B,S,1)
+    valid = t <= positions
+    if window > 0:
+        valid &= t > positions - window
+    if valid.ndim == 2:                                   # S = 1: (B, T)
+        return valid[:, None, None, None, :]
+    return valid[:, None, None]
+
+
 def _decode_step_attention_vec(params, cfg, x, cache, cache_len,
                                positions3, window, active):
     """Vector-``cache_len`` decode step (see decode_step_attention).
 
     PRECONDITION (uncheckable at trace time — the serving engines
-    enforce it via ``max_context`` validation): every ``cache_len[b]``
-    < slots, i.e. a NON-ring cache where slot t holds absolute position
-    t.  A ring cache would silently drop writes (``t == cache_len[b]``
-    never matches once positions wrap) and mis-mask stale slots.
+    enforce it via ``max_context`` validation): every active token's
+    position < slots, i.e. a NON-ring cache where slot t holds absolute
+    position t.  A ring cache would silently drop writes (``t ==
+    position`` never matches once positions wrap) and mis-mask stale
+    slots.
     """
-    B = x.shape[0]
+    B, S = x.shape[:2]
     slots = cache["k"].shape[1]
-    positions = cache_len[:, None]                        # (B, 1)
+    positions = _token_positions(cache_len, S)            # (B, S)
     if positions3 is None and cfg.mrope_sections:
-        positions3 = jnp.broadcast_to(positions, (3, B, 1))
+        positions3 = jnp.broadcast_to(positions, (3, B, S))
     q, k_new, v_new = qkv_project(params, cfg, x, positions, positions3)
 
     t = jnp.arange(slots, dtype=jnp.int32)[None, :]       # (1, T)
     with jax.named_scope("kv_write"):
-        write = t == positions                            # (B, T)
-        if active is not None:
-            write &= active[:, None]
-        k = jnp.where(write[:, :, None, None],
-                      k_new.astype(cache["k"].dtype), cache["k"])
-        v = jnp.where(write[:, :, None, None],
-                      v_new.astype(cache["v"].dtype), cache["v"])
+        k, v = cache["k"], cache["v"]
+        # a select over the cache a new token: XLA fuses them into one
+        # pass over it
+        for s in range(S):
+            write = t == positions[:, s:s + 1]            # (B, T)
+            if active is not None:
+                write &= active[:, None] if S == 1 else active[:, s:s + 1]
+            k = jnp.where(write[:, :, None, None],
+                          k_new[:, s:s + 1].astype(k.dtype), k)
+            v = jnp.where(write[:, :, None, None],
+                          v_new[:, s:s + 1].astype(v.dtype), v)
 
-    valid = t <= positions
-    w = window or cfg.sliding_window
-    if w > 0:
-        valid &= t > positions - w
-    mask = valid[:, None, None, None, :]                  # (B,1,1,S=1,T)
+    mask = _token_mask(t, positions, window or cfg.sliding_window)
     ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask,
                  softmax_scale(cfg))
-    out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, 1, -1),
+    out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, S, -1),
                      params["wo"].astype(x.dtype))
     return out, {"k": k, "v": v, "pos": cache["pos"]}
 
@@ -405,17 +435,18 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
     ``cache`` holds one block pool per layer (``k_pool``/``v_pool``,
     shape ``(nb + 1, bs, K, D)`` — last row is the scratch block);
     ``block_tables`` (B, blocks_per_seq) int32 maps each row's logical
-    block index to a physical pool row.  The new token is scattered into
-    the physical block covering position ``cache_len[b]`` (gated-off
-    rows write the scratch block instead), then K/V are gathered through
-    the table and attended exactly like the dense vector path — the
-    masked-softmax structure is identical, so greedy streams stay
-    bit-identical to the dense cache (garbage in unwritten/scratch
-    positions is masked to an exact 0 contribution).
+    block index to a physical pool row.  Each new token is scattered
+    into the physical block covering its position (gated-off tokens
+    write the scratch block instead), all B x S of them in one scatter;
+    then K/V are gathered through the table once and attended exactly
+    like the dense vector path — the masked-softmax structure is
+    identical, so greedy streams stay bit-identical to the dense cache
+    (garbage in unwritten/scratch positions is masked to an exact 0
+    contribution).
 
     With ``layer`` given, the pools are every scanned layer's, stacked
     ``(n_rep, nb + 1, bs, K, D)``, and ``layer`` leads every index: the
-    token lands in place in the carried stack and the gather reads only
+    tokens land in place in the carried stack and the gather reads only
     the table's blocks, so no layer's pool is sliced out or written
     back whole.
 
@@ -423,29 +454,34 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
     blocks are full, immutable and live strictly below every row's write
     position — see BlockKVCache.check_write).
     """
-    B = x.shape[0]
+    B, S = x.shape[:2]
     pool_k, pool_v = cache["k_pool"], cache["v_pool"]
     lead = () if layer is None else (layer,)
     nb_total, bs = pool_k.shape[len(lead)], pool_k.shape[len(lead) + 1]
     scratch = nb_total - 1
     bps = block_tables.shape[1]
     T = bps * bs
-    positions = cache_len[:, None]                        # (B, 1)
+    positions = _token_positions(cache_len, S)            # (B, S)
     if positions3 is None and cfg.mrope_sections:
-        positions3 = jnp.broadcast_to(positions, (3, B, 1))
+        positions3 = jnp.broadcast_to(positions, (3, B, S))
     q, k_new, v_new = qkv_project(params, cfg, x, positions, positions3)
 
-    # scatter the new token into its physical block
+    # scatter the new tokens into their physical blocks: indexed by row
+    # (B,) for one token a row, by token (B, S) for S
     with jax.named_scope("kv_write"):
-        lblk = jnp.clip(cache_len // bs, 0, bps - 1)      # logical block
-        bids = jnp.take_along_axis(block_tables, lblk[:, None], 1)[:, 0]
-        offs = cache_len % bs
+        pos = cache_len if S == 1 else positions
+        lblk = jnp.clip(pos // bs, 0, bps - 1)            # logical block
+        if S == 1:
+            bids = jnp.take_along_axis(block_tables, lblk[:, None], 1)[:, 0]
+        else:
+            bids = jnp.take_along_axis(block_tables, lblk, 1)
+        offs = pos % bs
         if active is not None:
             bids = jnp.where(active, bids, scratch)
         pool_k = pool_k.at[lead + (bids, offs)].set(
-            k_new[:, 0].astype(pool_k.dtype))
+            (k_new[:, 0] if S == 1 else k_new).astype(pool_k.dtype))
         pool_v = pool_v.at[lead + (bids, offs)].set(
-            v_new[:, 0].astype(pool_v.dtype))
+            (v_new[:, 0] if S == 1 else v_new).astype(pool_v.dtype))
 
     # gather the row's cache view through its block table
     with jax.named_scope("kv_gather"):
@@ -453,13 +489,9 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
         v = pool_v[lead + (block_tables,)].reshape(B, T, *pool_v.shape[-2:])
 
     t = jnp.arange(T, dtype=jnp.int32)[None, :]           # (1, T)
-    valid = t <= positions
-    w = window or cfg.sliding_window
-    if w > 0:
-        valid &= t > positions - w
-    mask = valid[:, None, None, None, :]                  # (B,1,1,S=1,T)
+    mask = _token_mask(t, positions, window or cfg.sliding_window)
     ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask,
                  softmax_scale(cfg))
-    out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, 1, -1),
+    out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, S, -1),
                      params["wo"].astype(x.dtype))
     return out, {"k_pool": pool_k, "v_pool": pool_v}

@@ -146,10 +146,13 @@ def init_paged_block_cache(cfg, kind, batch, num_blocks, block_size,
 def decode_block(params, cfg, x, cache, kind, cache_len,
                  positions3=None, moe_impl="ragged", mesh=None,
                  active=None, block_tables=None, layer=None):
-    """Single-token decode block.  x: (B, 1, d).
+    """Decode block.  x: (B, S, d), S new tokens a row at positions
+    ``cache_len[b] + s``; the Mamba mixer takes one (S = 1).
 
-    ``active`` (B,) bool gates per-row cache updates (continuous
-    batching: inactive slot-table rows must not mutate their caches).
+    ``active`` (B,) bool — (B, S) for S > 1 — gates per-row (per-token)
+    cache updates (continuous batching: inactive slot-table rows must
+    not mutate their caches).  The norms and the channel mix run on all
+    B * S rows.
     ``block_tables`` (B, blocks_per_seq) routes paged attention caches
     (see attention.init_paged_kv_cache); ignored by dense caches.
     ``layer`` indexes a paged cache whose pools are stacked over the
@@ -183,6 +186,9 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
                                              layer=layer)
             x = _residual(cfg, x, y)
     else:
+        if x.shape[1] > 1:
+            raise ValueError("the Mamba decode step consumes one token a "
+                             "row")
         with jax.named_scope("ssm"):
             y, new_cache = mamba_decode_step(params["mamba"], cfg, h,
                                              cache)

@@ -114,7 +114,8 @@ def _build_decoder_lm(cfg, distributed, mesh, long_context):
             params, cfg, caches, batch["tokens"], batch["cache_len"],
             batch.get("positions3"), moe_impl=moe_impl, mesh=mesh,
             active=batch.get("active"),
-            block_tables=batch.get("block_tables"))
+            block_tables=batch.get("block_tables"),
+            logits_at=batch.get("logits_at"))
 
     def init_caches(batch, max_len, dtype, ring=False):
         return transformer.init_caches(cfg, batch, max_len, dtype, ring)

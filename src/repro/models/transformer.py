@@ -184,8 +184,8 @@ def prefill_lm(params, cfg, tokens, frontend_embeds=None, positions3=None,
 
 def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
               moe_impl="ragged", mesh=None, active=None,
-              block_tables=None):
-    """One decode step.  tokens: (B, 1) -> (logits (B, V), new caches).
+              block_tables=None, logits_at=None):
+    """One decode step.  tokens: (B, S) -> (logits (B, V), new caches).
 
     ``cache_len`` may be a scalar (all rows at the same position) or a
     (B,) vector (continuous batching: per-slot positions); ``active``
@@ -193,6 +193,14 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     ``block_tables`` (B, blocks_per_seq) must be passed when ``caches``
     were built by :func:`init_paged_caches` (one table routes every
     layer's pool).
+
+    S > 1 runs a block of S tokens a row, at positions ``cache_len[b] +
+    s``, through every layer at once (attention-only models: the Mamba
+    step takes one token): ``active`` is then (B, S), one flag a token,
+    and the logits returned are row b's at position ``logits_at[b]``
+    (default S - 1).  A row whose final hidden state is not finite at
+    any of its active positions gets NaN logits, so a watchdog over the
+    returned logits covers every token of the block.
 
     Each paged attention pool of the period rides in the layer scan's
     carry, stacked ``(n_rep, nb + 1, bs, K, D)`` as the cache holds it,
@@ -208,8 +216,11 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     weights (and of scanned caches) carry no model scope.
     """
     pattern, prefix_len, period, n_rep = structure(cfg)
+    B, S = tokens.shape
+    if active is not None and S == 1:
+        active = active.reshape(B)           # one token a row: (B,)
     with jax.named_scope("embed"):
-        x = _embed(params, cfg, tokens)                # (B, 1, d)
+        x = _embed(params, cfg, tokens)                # (B, S, d)
 
     new_prefix = []
     for i in range(prefix_len):
@@ -253,6 +264,16 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     norm = make_norm(cfg.norm_type, cfg.rms_norm_eps)
     with jax.named_scope("norm"):
         hidden = norm(params["final_norm"], x)
+    if S > 1:
+        if logits_at is None:
+            logits_at = jnp.full((B,), S - 1, jnp.int32)
+        finite = jnp.isfinite(hidden).all(-1)
+        if active is not None:
+            finite |= ~active
+        healthy = finite.all(-1)
+        hidden = jnp.take_along_axis(hidden, logits_at[:, None, None], 1)
     with jax.named_scope("lm_head"):
         logits = logits_last_token(params, cfg, hidden)
+    if S > 1:
+        logits = jnp.where(healthy[:, None], logits, jnp.nan)
     return logits, {"prefix": new_prefix, "period": new_period}

@@ -364,7 +364,8 @@ class ServingEngine:
             self._m_dispatches.inc()
             with rec.region("prefill_chunk",
                             rows=int((n_valid > 0).sum()),
-                            tokens=int(n_valid.sum())):
+                            tokens=int(n_valid.sum()),
+                            block=self.stepper.block_chunk):
                 caches, _, first, _ = self.stepper.prefill_chunk(
                     self.params, caches, toks[:, t:t + C], lens, n_valid)
                 done_here = (t < plens) & (plens <= t + C)
@@ -1138,9 +1139,12 @@ class ContinuousEngine:
 
     def _prefill(self) -> None:
         """Chunked prefill — dispatched only when the pending prompt
-        tokens amortize a chunk's fixed scan cost (a chunk always runs
-        ``prefill_chunk`` masked steps); short prompt tails instead ride
-        the per-iteration decode dispatch for free (_decode)."""
+        tokens amortize a chunk's fixed cost (a chunk always computes
+        ``prefill_chunk`` tokens a row, masked: one (B, C) block through
+        the layer scan, or as many one-token steps where the model has
+        SSM layers — ``Stepper.block_chunk``); short prompt tails
+        instead ride the per-iteration decode dispatch for free
+        (_decode)."""
         pre = [s for s in range(self.max_batch)
                if self.slot_phase[s] == PREFILL]
         if not pre:
@@ -1163,7 +1167,8 @@ class ContinuousEngine:
         self._m_dispatches.inc()
         rec, it = self._rec, self.iterations
         with rec.region("prefill_chunk", iteration=it, rows=len(pre),
-                        tokens=int(n_valid.sum())):
+                        tokens=int(n_valid.sum()),
+                        block=self.stepper.block_chunk):
             with rec.region("dispatch", iteration=it):
                 self.caches, _, first, bad_dev = self.stepper.prefill_chunk(
                     self.params, self.caches, toks, self.slot_len, n_valid,

@@ -6,12 +6,18 @@ One :class:`Stepper` owns exactly two jitted callables per batch shape:
   advances from its own ``cache_len`` with an ``active`` validity mask,
   greedy sampling fused in-trace, so requests join and leave between
   iterations without retracing or re-dispatching per request.
-* ``prefill_chunk`` — an in-trace ``lax.scan`` consuming a fixed-width
-  chunk of ``prefill_chunk`` tokens per row.  Per-row ``n_valid`` masks
-  ragged prompt tails (and rows that are not prefilling at all), so every
-  prompt length — full chunks, remainders, idle rows — compiles exactly
-  one trace per batch shape.  The logits at each row's *last* valid step
-  are captured in-carry and argmax'd, yielding the first generated token
+* ``prefill_chunk`` — a fixed-width chunk of ``prefill_chunk`` tokens
+  per row.  A model whose layers are all attention runs the chunk as
+  one ``(B, C)`` block through the layer scan: every weight is read
+  once and every layer's block table gathered once for the chunk's C
+  tokens, which attend causally within the chunk and over the table.
+  A model with an SSM layer (whose step consumes one token) runs an
+  in-trace ``lax.scan`` of C one-token steps instead.  Per-row
+  ``n_valid`` masks ragged prompt tails (and rows that are not
+  prefilling at all), so every prompt length — full chunks,
+  remainders, idle rows — compiles exactly one trace per batch shape.
+  Only the logits at each row's *last* valid position are computed (or
+  captured in-carry) and argmax'd, yielding the first generated token
   without materializing per-position logits.
 * ``megastep`` — N fused decode iterations as ONE dispatch: an in-trace
   ``lax.scan`` whose carry is (caches, last sampled token, per-row
@@ -49,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.blocks import block_pattern
 from .sampling import (greedy_serving, logits_watchdog, megastep_advance,
                        poison_logits, select_tokens)
 from .telemetry import MetricsRegistry
@@ -110,6 +117,13 @@ class Stepper:
         # apart so the clean counters' no-retrace assertions stay exact
         self._m_poisoned_traces = m.counter("stepper.poisoned_traces")
         self._m_dispatches = m.counter("stepper.dispatches")
+        # chunk dispatches that ran the (B, C) block program
+        self._m_block_chunks = m.counter("stepper.block_chunks")
+        #: the chunk runs as one (B, C) block through the layer scan —
+        #: a decoder whose every layer is attention; an SSM step, and
+        #: the encoder-decoder's decode step, consume one token
+        self.block_chunk = not self.cfg.is_encoder_decoder and all(
+            mixer == "attn" for mixer, _ in block_pattern(self.cfg))
         # distinct megastep lengths traced, per flavor: a (flavor, N)
         # re-appearing would mean a RE-trace (tests assert counters ==
         # set sizes, i.e. one trace per distinct scan length)
@@ -242,11 +256,33 @@ class Stepper:
     def _make_chunk(self, paged: bool):
         decode = self.api.decode_fn
 
-        def run_chunk(params, caches, toks, lens, n_valid, tables=None):
-            if paged:                        # trace-time side effects
+        def count_trace():                   # trace-time side effects
+            if paged:
                 self._m_paged_chunk_traces.inc()
             else:
                 self._m_chunk_traces.inc()
+
+        def run_block(params, caches, toks, lens, n_valid, tables=None):
+            count_trace()
+            C = toks.shape[1]
+            batch = {"tokens": toks, "cache_len": lens,
+                     "active": jnp.arange(C, dtype=jnp.int32)
+                     < n_valid[:, None],
+                     "logits_at": jnp.maximum(n_valid - 1, 0)}
+            if tables is not None:
+                batch["block_tables"] = tables
+            logits, caches = decode(params, caches, batch)
+            with jax.named_scope("sample"):
+                started = n_valid > 0
+                first = jnp.where(started, greedy_serving(logits), 0)
+                bad = logits_watchdog(logits, started)
+            return caches, lens + n_valid, first, bad
+
+        if self.block_chunk:
+            return run_block
+
+        def run_chunk(params, caches, toks, lens, n_valid, tables=None):
+            count_trace()
             B, C = toks.shape
 
             def step(carry, x):
@@ -280,8 +316,10 @@ class Stepper:
         tokens for row b starting at its ``lens[b]`` cache position.
         Returns (caches, new lens, first-token per row — meaningful only
         for rows whose prompt completed inside this chunk, watchdog flag
-        per row OR-ed over the chunk's steps)."""
+        per row OR-ed over the chunk's tokens)."""
         self._m_dispatches.inc()
+        if self.block_chunk:
+            self._m_block_chunks.inc()
         if block_tables is None:
             return self._chunk(params, caches, _device(toks, jnp.int32),
                                _device(lens, jnp.int32),

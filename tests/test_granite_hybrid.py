@@ -303,6 +303,33 @@ def test_dense_step_programs_and_streams_unchanged(arch):
         == streams
 
 
+#: The paged ``jit_run_chunk`` of each hybrid configuration at
+#: ``reduced()`` (batch 2, 4 blocks of 4 a row, a chunk of 4), as
+#: StableHLO text, recorded from the program before attention-only
+#: models took the chunk as one block: a model with SSM layers keeps
+#: the scan of one-token steps, op for op.
+HYBRID_CHUNK = {
+    "granite-4.0-h-small":
+        "dd7f8392dd296cd7df1e2026b6b9b2917cc1a4229edb0b6b89372957bf15419f",
+    "jamba-v0.1-52b":
+        "fb5a981ac10fa56a05556519add387669a2c5bed34876dfbc12c6efc3e49492d",
+}
+
+
+@pytest.mark.parametrize("arch", sorted(HYBRID_CHUNK))
+def test_hybrid_chunk_program_unchanged(arch):
+    api = build_model(get_config(arch).reduced())
+    params = api.init(jax.random.key(0))
+    B, BPS, BS, C = 2, 4, 4, 4
+    caches = api.init_paged_caches(B, B * BPS, BS, jnp.float32)
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    stepper = Stepper(api)
+    assert not stepper.block_chunk
+    text = stepper._chunk_paged.lower(
+        params, caches, i32(B, C), i32(B), i32(B), i32(B, BPS)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == HYBRID_CHUNK[arch]
+
+
 def test_state_reset_is_its_own_program_span_and_counter():
     """Every admission wave clears its rows' SSM and conv state in one
     dispatch of the reset program, inside an ``engine.reset`` span; the

@@ -23,6 +23,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.runtime.config import EngineConfig
 from repro.runtime.engine import ContinuousEngine, Request
 from repro.runtime.faults import FaultEvent, FaultPlane
 from repro.runtime.kv_cache import BlockKVCache
@@ -481,6 +482,32 @@ def test_fused_iterations_semantics(model, shared_stepper):
     assert e8.iterations < e1.iterations
     assert e8.stats()["counters"]["engine.fused_iterations"] \
         == e8.fused_iterations
+
+
+@pytest.mark.parametrize("arch,block", [("stablelm-3b", True),
+                                        ("granite-4.0-h-small", False)])
+def test_block_chunks_count_every_block_dispatch(arch, block):
+    """``stepper.block_chunks`` counts the chunk dispatches that ran the
+    (B, C) block program: every one of an attention-only model's, none
+    of the hybrid's (its Mamba step takes one token); each
+    ``prefill_chunk`` span says which program it ran."""
+    api = build_model(get_config(arch).reduced())
+    cfg = api.cfg
+    tele = Telemetry(trace=True)
+    eng = ContinuousEngine(api, api.init(jax.random.key(0)),
+                           telemetry=tele, config=EngineConfig(
+                               max_batch=3, max_context=32, prefill_chunk=4,
+                               block_size=4, megastep=4,
+                               hbm_budget=1 << 30, host_pool=0,
+                               prefix_cache=False, fault_seed=None))
+    for i, p in enumerate(_prompts(cfg, 4, plen=11)):
+        eng.submit(Request(i, p, max_new_tokens=3))
+    eng.run()
+    chunks = [e for e in tele.events if e["kind"] == "prefill_chunk"]
+    assert chunks
+    assert all(e["args"]["block"] is block for e in chunks)
+    count = eng.stats()["stepper"]["stepper.block_chunks"]
+    assert count == (len(chunks) if block else 0)
 
 
 # -- tracing invariance (pinned child, like all stream-identity tests) -------

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.branch_matmul.ops import grouped_branch_matmul
 from repro.models import build_model
+from repro.models.transformer import structure
 from repro.runtime.stepper import Stepper
 from repro.utils.hlo import loop_body_ops
 
@@ -150,15 +152,27 @@ XS_POOL_TEMP_BYTES = {
 }
 
 
+_COMPILED = {}
+
+
+def _compiled(one_chip, arch, step):
+    """(compiled program, caches, config) of ``step`` for ``arch``,
+    compiled once for the file's tests."""
+    if (arch, step) not in _COMPILED:
+        stepper, params, caches, args = _serving_shapes(arch, one_chip)
+        fn, *rest = args[step](stepper)
+        _COMPILED[arch, step] = (fn.lower(params, caches, *rest).compile(),
+                                 caches, stepper.cfg)
+    return _COMPILED[arch, step]
+
+
 @pytest.mark.parametrize("step", ["megastep", "prefill_chunk"])
 @pytest.mark.parametrize("arch", ["stablelm-3b", "h2o-danube-3-4b"])
 def test_layer_scan_moves_no_whole_pool(one_chip, arch, step):
     """The stacked KV pool rides in the layer scan's carry: no loop body
     copies, slices or writes back the pool or one layer's pool; each
     step writes its token in place and gathers through the table."""
-    stepper, params, caches, args = _serving_shapes(arch, one_chip)
-    fn, *rest = args[step](stepper)
-    compiled = fn.lower(params, caches, *rest).compile()
+    compiled, caches, _ = _compiled(one_chip, arch, step)
     pool = caches["period"][0]["k_pool"].shape
     layer_pool = (1,) + pool[1:]
     moved = [(op, dims) for op, dims in loop_body_ops(compiled.as_text())
@@ -167,3 +181,30 @@ def test_layer_scan_moves_no_whole_pool(one_chip, arch, step):
     assert not moved, moved
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= XS_POOL_TEMP_BYTES[arch, step]
+
+
+def _loops(hlo_text):
+    """[(trip count, carried array shapes)] of every ``while`` of a
+    compiled program: the count is the constant its condition compares
+    the induction variable with."""
+    out = []
+    for m in re.finditer(r"= \((?P<carry>.*)\) while\(.*?condition="
+                         r"%?(?P<cond>[\w.\-]+)", hlo_text):
+        body = re.search(rf"^%?{re.escape(m['cond'])} .*?^}}", hlo_text,
+                         re.M | re.S)[0]
+        trips = int(re.search(r"constant\((\d+)\)", body)[1])
+        shapes = [tuple(int(d) for d in dims.split(",") if d)
+                  for dims in re.findall(r"\[([0-9,]*)\]", m["carry"])]
+        out.append((trips, shapes))
+    return out
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "h2o-danube-3-4b"])
+def test_chunk_runs_one_block_through_the_layer_scan(one_chip, arch):
+    """An attention-only model's chunk program holds one loop, the layer
+    scan, run ``n_rep`` times with the whole (B, C, d) block in its
+    carry: no loop runs over the chunk's tokens."""
+    compiled, _, cfg = _compiled(one_chip, arch, "prefill_chunk")
+    (trips, carry), = _loops(compiled.as_text())
+    assert trips == structure(cfg)[3]             # n_rep
+    assert (MAX_BATCH, CHUNK, cfg.d_model) in carry
