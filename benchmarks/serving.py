@@ -3,9 +3,9 @@
 A mixed workload (short-prompt/long-generation and long-prompt/short-
 generation requests with equal §3.3 peak-memory cost, so both kinds land
 in the same admission rounds) runs through both engines sharing ONE
-pre-traced Stepper — the continuous engine on its physically paged
-block cache.  Reports and persists to ``BENCH_serving.json`` (written
-to the repo root regardless of CWD; override with ``--out``):
+pre-traced Stepper on one physically paged block pool shape.  Reports
+and persists to ``BENCH_serving.json`` (written to the repo root
+regardless of CWD; override with ``--out``):
 
 * throughput (generated tokens / wall-second) per engine,
 * p50 / p95 TTFT per engine, in BOTH accountings: run start -> first
@@ -385,9 +385,7 @@ def main():
     # request's TTFT) pays compiles: run the REAL workload once through
     # both engines and every megastep length the sweep measures — the
     # megastep traces one executable per distinct scan length, so only
-    # the full workload exercises them all (the round engine's pass
-    # covers the dense chunk/decode twins; every measured continuous
-    # engine is paged)
+    # the full workload exercises them all
     for warm in ([mk_round()] +
                  [mk_cont(m) for m in sorted({1, 4, 8, args.megastep})]):
         for r in reqs:
@@ -405,7 +403,6 @@ def main():
     cont_stats["megasteps"] = cont.megasteps
     cont_stats["megastep_steps"] = cont.megastep_steps
     cont_stats["megastep_n"] = cont.megastep_n
-    cont_stats["paged"] = cont.paged
     cont_stats["fused_iterations"] = cont.fused_iterations
     cont_stats["peak_physical_blocks"] = cont.kv.physical_kv_blocks
     # degraded-mode counters now live in the telemetry snapshot below

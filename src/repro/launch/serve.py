@@ -15,7 +15,7 @@ weights are placed.
 
 Every engine knob flag (``--hbm-budget``, ``--max-batch``,
 ``--megastep``, ``--host-pool``, ``--fault-seed``, ``--max-queue``,
-``--paged/--no-paged``, ...) is **generated** from
+``--prefix-sharing/--no-prefix-sharing``, ...) is **generated** from
 :class:`repro.runtime.config.EngineConfig` — run ``--help`` for the
 full table.  An omitted flag falls back to its ``PARALLAX_*`` env var,
 then the field default (explicit always wins, including falsy values
@@ -23,9 +23,8 @@ like ``--host-pool 0``), so the CLI, the env knobs, and the
 constructor can never drift apart.
 
 ``--engine continuous`` serves through the iteration-level slot-table
-engine on the physically paged block KV cache with cross-request
-prefix sharing (decoder-only models); ``--no-paged`` falls back to the
-dense per-slot cache baseline.
+engine with cross-request prefix sharing (decoder-only models).  Both
+engines keep KV in the physically paged block pool.
 
 **Closed loop** (the default): all requests are submitted up front and
 ``run()`` drains them — a throughput measurement.  **Open loop**:
@@ -92,7 +91,7 @@ def serve(arch: str, n_requests: int = 8, max_new: int = 16,
           budget_mb: "int | None" = None, prompt_len: int = 12,
           seed: int = 0,
           max_batch: int = 4, engine_mode: str = "round",
-          paged: bool = True, megastep: "int | None" = None,
+          megastep: "int | None" = None,
           fault_seed: "int | None" = None,
           max_queue: "int | None" = None,
           deadline_s: "float | None" = None,
@@ -122,7 +121,6 @@ def serve(arch: str, n_requests: int = 8, max_new: int = 16,
         config = EngineConfig(
             hbm_budget=None if budget_mb is None else budget_mb << 20,
             max_batch=max_batch,
-            paged=paged,
             max_context=(prompt_len + max_new
                          if engine_mode == "continuous" else None),
             **{k: v for k, v in dict(

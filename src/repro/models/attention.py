@@ -285,9 +285,10 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
 
     ``cache_len`` is the number of tokens already generated/prefilled;
     row b's new tokens have absolute positions ``cache_len[b] + s``.
-    S > 1 (a prefill chunk as one block) needs vector ``cache_len`` and
+    S > 1 (a prefill chunk as one block) runs on a paged pool only, and
     takes ``active`` as (B, S), one flag a token; the tokens' K/V are
     written first, and token s attends every position up to its own.
+    A dense cache takes one token a row.
 
     *Scalar* ``cache_len`` (may be traced): every row is at the same
     position; the token is written to slot ``cache_len % slots`` (ring
@@ -328,15 +329,17 @@ def decode_step_attention(params, cfg, x, cache, cache_len,
         return _decode_step_attention_paged(
             params, cfg, x, cache, cache_len, block_tables, positions3,
             window, active, layer)
+    if S > 1:
+        raise ValueError("a dense KV cache takes one token a row: "
+                         "multi-token steps run on the paged pool")
     slots = cache["k"].shape[1]
     if cache_len.ndim == 1:
         return _decode_step_attention_vec(params, cfg, x, cache, cache_len,
                                           positions3, window, active)
-    if active is not None or S > 1:
+    if active is not None:
         raise ValueError(
-            "per-row `active` gating and multi-token steps require vector "
-            "cache_len (B,): the scalar path writes one token a row, "
-            "unconditionally")
+            "per-row `active` gating requires vector cache_len (B,): the "
+            "scalar path writes every row's cache unconditionally")
     positions = jnp.broadcast_to(cache_len, (B, 1))
     if positions3 is None and cfg.mrope_sections:
         positions3 = jnp.broadcast_to(positions, (3, B, 1))
@@ -389,40 +392,36 @@ def _token_mask(t, positions, window: int):
 
 def _decode_step_attention_vec(params, cfg, x, cache, cache_len,
                                positions3, window, active):
-    """Vector-``cache_len`` decode step (see decode_step_attention).
+    """Vector-``cache_len`` decode step, one token a row (see
+    decode_step_attention).
 
-    PRECONDITION (uncheckable at trace time — the serving engines
-    enforce it via ``max_context`` validation): every active token's
-    position < slots, i.e. a NON-ring cache where slot t holds absolute
-    position t.  A ring cache would silently drop writes (``t ==
-    position`` never matches once positions wrap) and mis-mask stale
-    slots.
+    PRECONDITION (uncheckable at trace time — the callers bound it by
+    ``max_context``): every ``cache_len[b]`` < slots, i.e. a NON-ring
+    cache where slot t holds absolute position t.  A ring cache would
+    silently drop writes (``t == cache_len[b]`` never matches once
+    positions wrap) and mis-mask stale slots.
     """
-    B, S = x.shape[:2]
+    B = x.shape[0]
     slots = cache["k"].shape[1]
-    positions = _token_positions(cache_len, S)            # (B, S)
+    positions = cache_len[:, None]                        # (B, 1)
     if positions3 is None and cfg.mrope_sections:
-        positions3 = jnp.broadcast_to(positions, (3, B, S))
+        positions3 = jnp.broadcast_to(positions, (3, B, 1))
     q, k_new, v_new = qkv_project(params, cfg, x, positions, positions3)
 
     t = jnp.arange(slots, dtype=jnp.int32)[None, :]       # (1, T)
     with jax.named_scope("kv_write"):
-        k, v = cache["k"], cache["v"]
-        # a select over the cache a new token: XLA fuses them into one
-        # pass over it
-        for s in range(S):
-            write = t == positions[:, s:s + 1]            # (B, T)
-            if active is not None:
-                write &= active[:, None] if S == 1 else active[:, s:s + 1]
-            k = jnp.where(write[:, :, None, None],
-                          k_new[:, s:s + 1].astype(k.dtype), k)
-            v = jnp.where(write[:, :, None, None],
-                          v_new[:, s:s + 1].astype(v.dtype), v)
+        write = t == positions                            # (B, T)
+        if active is not None:
+            write &= active[:, None]
+        k = jnp.where(write[:, :, None, None],
+                      k_new.astype(cache["k"].dtype), cache["k"])
+        v = jnp.where(write[:, :, None, None],
+                      v_new.astype(cache["v"].dtype), cache["v"])
 
     mask = _token_mask(t, positions, window or cfg.sliding_window)
     ctx = attend(q, k.astype(q.dtype), v.astype(q.dtype), mask,
                  softmax_scale(cfg))
-    out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, S, -1),
+    out = jnp.einsum("bsf,fd->bsd", ctx.reshape(B, 1, -1),
                      params["wo"].astype(x.dtype))
     return out, {"k": k, "v": v, "pos": cache["pos"]}
 
@@ -440,9 +439,8 @@ def _decode_step_attention_paged(params, cfg, x, cache, cache_len,
     write the scratch block instead), all B x S of them in one scatter;
     then K/V are gathered through the table once and attended exactly
     like the dense vector path — the masked-softmax structure is
-    identical, so greedy streams stay bit-identical to the dense cache
-    (garbage in unwritten/scratch positions is masked to an exact 0
-    contribution).
+    identical (garbage in unwritten/scratch positions is masked to an
+    exact 0 contribution).
 
     With ``layer`` given, the pools are every scanned layer's, stacked
     ``(n_rep, nb + 1, bs, K, D)``, and ``layer`` leads every index: the

@@ -147,7 +147,8 @@ def decode_block(params, cfg, x, cache, kind, cache_len,
                  positions3=None, moe_impl="ragged", mesh=None,
                  active=None, block_tables=None, layer=None):
     """Decode block.  x: (B, S, d), S new tokens a row at positions
-    ``cache_len[b] + s``; the Mamba mixer takes one (S = 1).
+    ``cache_len[b] + s``; the Mamba mixer and a dense KV cache take one
+    (S = 1).
 
     ``active`` (B,) bool — (B, S) for S > 1 — gates per-row (per-token)
     cache updates (continuous batching: inactive slot-table rows must

@@ -195,12 +195,13 @@ def decode_lm(params, cfg, caches, tokens, cache_len, positions3=None,
     layer's pool).
 
     S > 1 runs a block of S tokens a row, at positions ``cache_len[b] +
-    s``, through every layer at once (attention-only models: the Mamba
-    step takes one token): ``active`` is then (B, S), one flag a token,
-    and the logits returned are row b's at position ``logits_at[b]``
-    (default S - 1).  A row whose final hidden state is not finite at
-    any of its active positions gets NaN logits, so a watchdog over the
-    returned logits covers every token of the block.
+    s``, through every layer at once (attention-only models on paged
+    caches: the Mamba step and a dense cache take one token): ``active``
+    is then (B, S), one flag a token, and the logits returned are row
+    b's at position ``logits_at[b]`` (default S - 1).  A row whose final
+    hidden state is not finite at any of its active positions gets NaN
+    logits, so a watchdog over the returned logits covers every token of
+    the block.
 
     Each paged attention pool of the period rides in the layer scan's
     carry, stacked ``(n_rep, nb + 1, bs, K, D)`` as the cache holds it,

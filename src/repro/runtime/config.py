@@ -125,20 +125,15 @@ class EngineConfig:
         MEGASTEP_DEFAULT, env=MEGASTEP_ENV, parse=_parse_int,
         help="decode iterations fused per lax.scan dispatch "
              "(1 disables fusion)")
-    paged: bool = _knob(
-        True, parse=None,
-        help="physically paged block pool (dense per-slot caches when "
-             "off)")
     prefix_sharing: bool = _knob(
         True, parse=None,
-        help="share identical prompt-prefix blocks across live requests "
-             "(paged only)")
+        help="share identical prompt-prefix blocks across live requests")
     prefix_cache: bool = _knob(
         False, env=PREFIX_CACHE_ENV, parse=_parse_bool,
         help="retain finished requests' published prompt blocks in a "
              "persistent radix cache (LRU-evicted under pressure) so "
              "later identical prefixes skip prefill entirely "
-             "(paged attention-only archs; needs prefix_sharing)")
+             "(attention-only archs; needs prefix_sharing)")
     max_queue: "int | None" = _knob(
         None, parse=_parse_opt_int,
         help="admission-queue bound: submits beyond it are rejected "
@@ -214,10 +209,10 @@ class EngineConfig:
     def add_cli_args(cls, parser: argparse.ArgumentParser,
                      exclude: tuple = ()) -> None:
         """Add one generated flag per config field (``--max-batch``,
-        ``--host-pool``, boolean ``--paged/--no-paged``, ...).  Flags
-        default to *absent* so the config's own precedence applies:
-        an omitted flag falls through to the env var, then the field
-        default."""
+        ``--host-pool``, boolean ``--prefix-sharing/--no-prefix-sharing``,
+        ...).  Flags default to *absent* so the config's own precedence
+        applies: an omitted flag falls through to the env var, then the
+        field default."""
         group = parser.add_argument_group(
             "engine config (omitted flags fall back to PARALLAX_* env "
             "vars, then defaults; see runtime/config.py)")

@@ -48,7 +48,6 @@ float noise cannot flip token selection either.
 
 from __future__ import annotations
 
-import os
 import time
 import warnings
 from collections import deque
@@ -57,61 +56,12 @@ from dataclasses import dataclass, field
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.scheduler import (_parse_bytes, greedy_select,
-                                  incremental_select)
+from repro.core.scheduler import greedy_select, incremental_select
 from .config import EngineConfig
-from .kv_cache import BlockKVCache, KVCacheManager, request_peak_bytes
+from .kv_cache import (BlockKVCache, KVCacheManager, kv_bytes_per_token,
+                       request_peak_bytes)
 from .stepper import Stepper
 from .telemetry import Telemetry
-
-MEGASTEP_ENV = "PARALLAX_MEGASTEP"
-MEGASTEP_DEFAULT = 8
-HOST_POOL_ENV = "PARALLAX_HOST_POOL"
-
-
-def megastep_from_env(explicit: "int | None" = None) -> int:
-    """Resolve the decode-megastep length N: an explicit engine argument
-    wins, then the ``PARALLAX_MEGASTEP`` env var, then the default
-    (megastep ON with a safe N).  ``1`` selects the per-iteration path
-    exactly as it was before megasteps existed."""
-    if explicit is not None:
-        n = explicit
-    else:
-        raw = os.environ.get(MEGASTEP_ENV)
-        if raw is None:
-            return MEGASTEP_DEFAULT
-        try:
-            n = int(raw)
-        except ValueError:
-            raise ValueError(
-                f"{MEGASTEP_ENV}={raw!r}: expected an integer "
-                f"megastep length (1 disables fusion)") from None
-    if n < 1:
-        raise ValueError(f"megastep length must be >= 1, got {n}")
-    return n
-
-
-def host_pool_from_env(explicit: "int | None" = None) -> int:
-    """Resolve the host KV-tier pool size in bytes: an explicit engine
-    argument wins, then the ``PARALLAX_HOST_POOL`` env var (K/M/G/T
-    suffixes, e.g. ``512M``), then 0 — host tier disabled, demote-only
-    preemption exactly as before the tier existed."""
-    if explicit is not None:
-        n = int(explicit)
-    else:
-        raw = os.environ.get(HOST_POOL_ENV)
-        if raw is None or raw == "":
-            return 0
-        try:
-            n = _parse_bytes(raw)
-        except ValueError:
-            raise ValueError(
-                f"{HOST_POOL_ENV}={raw!r}: expected a byte count "
-                f"(supports K/M/G/T suffixes, 0 disables)") from None
-    if n < 0:
-        raise ValueError(f"host pool must be >= 0 bytes, got {n}")
-    return n
-
 
 def _shim_config(config: "EngineConfig | None", legacy: dict,
                  engine: str, exact: "dict | None" = None) -> EngineConfig:
@@ -220,12 +170,15 @@ class ServingEngine:
     """Round-based batched prefill + decode with §3.3 greedy admission.
 
     The measured baseline for :class:`ContinuousEngine`: whole-lifetime
-    peak-memory admission (`KVCacheManager`), one monolithic cache slab
-    per request, and round-at-a-time scheduling.  Prefill and decode run
-    through the shared :class:`Stepper`, so every row advances from its
-    own prompt length (length-correct streams) and the fixed-width
-    masked prefill chunk compiles exactly one trace per batch shape
-    regardless of prompt-length remainders.
+    peak-memory admission (`KVCacheManager`) and round-at-a-time
+    scheduling.  Each round builds a paged pool of ``bps`` =
+    ``ceil(max_context / block_size)`` blocks a row with a fixed
+    contiguous table (row b holds blocks ``b * bps .. b * bps + bps -
+    1``), and prefill and decode run through the same :class:`Stepper`
+    programs as the continuous engine: every row advances from its own
+    prompt length (length-correct streams), and the fixed-width masked
+    prefill chunk compiles exactly one trace per pool shape regardless
+    of prompt-length remainders.
     """
 
     _DYNAMIC_CTX = object()     # "max_context not passed" marker: the
@@ -251,6 +204,8 @@ class ServingEngine:
             dict(hbm_budget=hbm_budget_bytes, max_batch=max_batch,
                  margin=margin, prefill_chunk=prefill_chunk),
             "ServingEngine", exact=exact)
+        if api.init_paged_caches is None:
+            raise ValueError("model family has no paged decode path")
         self.config = config
         self.api = api
         self.cfg = api.cfg
@@ -353,7 +308,14 @@ class ServingEngine:
             toks[i, :len(r.prompt)] = r.prompt          # right padding
         toks = _pad_to_multiple(toks, C)
 
-        caches = self.api.init_caches(B, max_ctx, jnp.dtype(self.cfg.dtype))
+        # the round's pool, shaped as the continuous engine's for the
+        # same (max_batch, max_context, block_size): a model without
+        # attention layers holds no blocks and keeps one table column
+        bs = self.config.block_size
+        bps = -(-max_ctx // bs) if kv_bytes_per_token(self.cfg) else 1
+        tables = np.arange(B * bps, dtype=np.int32).reshape(B, bps)
+        caches = self.api.init_paged_caches(B, B * bps, bs,
+                                            jnp.dtype(self.cfg.dtype))
         lens = np.zeros(B, np.int32)
         first_tok = np.zeros(B, np.int32)
 
@@ -367,7 +329,8 @@ class ServingEngine:
                             tokens=int(n_valid.sum()),
                             block=self.stepper.block_chunk):
                 caches, _, first, _ = self.stepper.prefill_chunk(
-                    self.params, caches, toks[:, t:t + C], lens, n_valid)
+                    self.params, caches, toks[:, t:t + C], lens, n_valid,
+                    tables)
                 done_here = (t < plens) & (plens <= t + C)
                 if done_here.any():
                     first_host = np.asarray(first)
@@ -409,7 +372,7 @@ class ServingEngine:
             # must not drift with the hardening work
             with rec.region("decode", rows=int(active.sum())):
                 last_dev, _, caches = self.stepper.decode(
-                    self.params, caches, last, lens, active)
+                    self.params, caches, last, lens, active, tables)
                 last = np.asarray(last_dev)
             lens += active
             count += active
@@ -557,16 +520,14 @@ class ContinuousEngine:
     bit-identical streams by construction; N >= 2 preserves them
     because each scan step runs the very same per-row computation.
 
-    ``paged=True`` (default) stores KV in ONE physical block pool per
-    layer — ``BlockKVCache`` slab ids index the pool rows, and the
-    engine ships a ``(max_batch, blocks_per_seq)`` block table with
-    every dispatch, so block reuse reaches the memory the kernels read
-    (not just the byte accounting).  ``prefix_sharing=True`` maps
-    identical prompt prefixes of concurrently live requests onto the
-    same physical blocks (content-hashed full blocks, refcounted,
-    immutable): the shared tokens are neither re-prefilled nor
-    re-allocated.  ``paged=False`` keeps the dense per-slot arrays —
-    the bit-identical baseline the paged path is validated against.
+    KV lives in ONE physical block pool per layer — ``BlockKVCache``
+    slab ids index the pool rows, and the engine ships a ``(max_batch,
+    blocks_per_seq)`` block table with every dispatch, so block reuse
+    reaches the memory the kernels read (not just the byte accounting).
+    ``prefix_sharing=True`` maps identical prompt prefixes of
+    concurrently live requests onto the same physical blocks
+    (content-hashed full blocks, refcounted, immutable): the shared
+    tokens are neither re-prefilled nor re-allocated.
 
     **Robustness** (see ``runtime/faults.py``): every dispatch carries
     an in-trace NaN watchdog; a poisoned result degrades down a ladder —
@@ -582,7 +543,7 @@ class ContinuousEngine:
     than raising while a scheduled restore can regain feasibility —
     each stalled iteration is counted (``engine.stalls``) and traced
     with its cause and the pending restore's ETA.  **Host KV tier**
-    (``host_pool`` / env ``PARALLAX_HOST_POOL``, paged attention-only
+    (``host_pool`` / env ``PARALLAX_HOST_POOL``, attention-only
     models): preempted and admission-evicted blocks spill to a host
     byte pool instead of being discarded, and re-admission restores
     them bit-identically — zero re-prefill under memory pressure while
@@ -602,7 +563,6 @@ class ContinuousEngine:
                  block_size: "int | None" = None,
                  max_context: "int | None" = None,
                  stepper: "Stepper | None" = None,
-                 paged: "bool | None" = None,
                  prefix_sharing: "bool | None" = None,
                  megastep: "int | None" = None,
                  faults=None,
@@ -617,7 +577,7 @@ class ContinuousEngine:
             dict(hbm_budget=hbm_budget_bytes, max_batch=max_batch,
                  margin=margin, prefill_chunk=prefill_chunk,
                  block_size=block_size, max_context=max_context,
-                 paged=paged, prefix_sharing=prefix_sharing,
+                 prefix_sharing=prefix_sharing,
                  megastep=megastep, max_queue=max_queue,
                  dispatch_retries=dispatch_retries,
                  retry_backoff_s=retry_backoff_s, host_pool=host_pool),
@@ -628,7 +588,6 @@ class ContinuousEngine:
                              "on it); max_context=None is the round "
                              "engine's dynamic bucketing")
         self.config = config
-        paged = config.paged
         prefix_sharing = config.prefix_sharing
         max_batch = config.max_batch
         max_context = config.max_context
@@ -637,7 +596,7 @@ class ContinuousEngine:
             raise ValueError("ContinuousEngine serves decoder-only "
                              "models (encoder-decoder needs an encoder "
                              "pass the slot table does not schedule)")
-        if paged and api.init_paged_caches is None:
+        if api.init_paged_caches is None:
             raise ValueError("model family has no paged decode path")
         self.api = api
         self.cfg = api.cfg
@@ -652,17 +611,15 @@ class ContinuousEngine:
         self.telemetry = telemetry if telemetry is not None else Telemetry()
         self._rec = self.telemetry.rec
         m = self.telemetry.metrics
-        # host KV tier: only the paged path can spill (the dense cache
-        # has no physical block rows to capture), and BlockKVCache
-        # additionally gates on pure-attention archs (host_enabled)
-        self.host_pool_bytes = config.host_pool if paged else 0
+        # the host KV tier: BlockKVCache gates it on pure-attention
+        # archs (host_enabled)
         self.kv = BlockKVCache(self.cfg,
                                int(config.hbm_budget
                                    * (1.0 - config.margin)),
                                block_size, metrics=m,
-                               host_budget_bytes=self.host_pool_bytes,
+                               host_budget_bytes=config.host_pool,
                                prefix_cache=(bool(config.prefix_cache)
-                                             and paged and prefix_sharing))
+                                             and prefix_sharing))
         self.max_batch = max_batch
         self.prefill_chunk = config.prefill_chunk
         self.max_context = max_context
@@ -670,12 +627,11 @@ class ContinuousEngine:
             raise ValueError("shared stepper built for a different model")
         self.stepper = stepper if stepper is not None else Stepper(api)
         self._m_dispatches = m.counter("engine.dispatches")
-        self.paged = paged
         # sharing skips recompute of the shared tokens, which is only
         # sound when the WHOLE per-token state lives in the shared KV
         # blocks — any SSM/conv layer carries per-row state the skipped
         # tokens would never reach, so hybrid archs keep sharing off
-        self.prefix_sharing = (paged and prefix_sharing
+        self.prefix_sharing = (prefix_sharing
                                and self.kv.block_bytes > 0
                                and self.kv.state_bytes == 0)
         # the persistent prefix cache extends the same walk across
@@ -686,36 +642,30 @@ class ContinuousEngine:
         # spill/restore moves whole written-token state through the
         # host tier, sound under the same conditions as sharing: the
         # entire per-token state must live in the KV blocks
-        self.spill_enabled = paged and self.kv.host_enabled
-        if paged:
-            # physical pool rows: every table entry holding a distinct
-            # block bounds the ids BlockKVCache can ever issue, so the
-            # pool shape depends only on (max_batch, max_context,
-            # block_size) — engines differing just in budget share one
-            # compiled trace
-            self.blocks_per_seq = max(1, self.kv.blocks_for(max_context))
-            cap = max_batch * self.blocks_per_seq
-            self.num_blocks = cap
-            self.scratch_block = cap        # pool row cap = scratch
-            self.tables = np.full((max_batch, self.blocks_per_seq),
-                                  self.scratch_block, np.int32)
-            self.caches = api.init_paged_caches(
-                max_batch, self.num_blocks, block_size,
-                jnp.dtype(self.cfg.dtype))
-            # cache-tier retention may exhaust the pool's free list; cap
-            # the slab ids the kv can mint so it recycles cached rows
-            # instead of indexing past the paged pools' physical rows
-            self.kv.row_cap = self.num_blocks
-            if self.prefix_cache:
-                self.kv.rec = self._rec
-                if self.kv.host_enabled:
-                    # evicted cached rows take a second chance host-side
-                    self.kv.capture_hook = self._capture_blocks
-                    self.kv.scatter_hook = self._scatter_blocks
-        else:
-            self.tables = None
-            self.caches = api.init_caches(max_batch, max_context,
-                                          jnp.dtype(self.cfg.dtype))
+        self.spill_enabled = self.kv.host_enabled
+        # physical pool rows: every table entry holding a distinct block
+        # bounds the ids BlockKVCache can ever issue, so the pool shape
+        # depends only on (max_batch, max_context, block_size) — engines
+        # differing just in budget share one compiled trace
+        self.blocks_per_seq = max(1, self.kv.blocks_for(max_context))
+        cap = max_batch * self.blocks_per_seq
+        self.num_blocks = cap
+        self.scratch_block = cap            # pool row cap = scratch
+        self.tables = np.full((max_batch, self.blocks_per_seq),
+                              self.scratch_block, np.int32)
+        self.caches = api.init_paged_caches(
+            max_batch, self.num_blocks, block_size,
+            jnp.dtype(self.cfg.dtype))
+        # cache-tier retention may exhaust the pool's free list; cap the
+        # slab ids the kv can mint so it recycles cached rows instead of
+        # indexing past the paged pools' physical rows
+        self.kv.row_cap = self.num_blocks
+        if self.prefix_cache:
+            self.kv.rec = self._rec
+            if self.kv.host_enabled:
+                # evicted cached rows take a second chance host-side
+                self.kv.capture_hook = self._capture_blocks
+                self.kv.scatter_hook = self._scatter_blocks
 
         self.slots: "list[_Seq | None]" = [None] * max_batch
         self.slot_len = np.zeros(max_batch, np.int32)
@@ -981,7 +931,6 @@ class ContinuousEngine:
         snap["derived"] = {
             "degraded_activations": self.degraded_activations,
             "megastep_n": self.megastep_n,
-            "paged": self.paged,
             "spill_enabled": self.spill_enabled,
             "host_pool_bytes": self.kv.host_budget,
             "prefix_cache": self.prefix_cache,
@@ -1130,8 +1079,6 @@ class ContinuousEngine:
     def _refresh_table(self, slot: int) -> None:
         """Mirror the slot's BlockKVCache table into the np block table
         shipped with every dispatch (unallocated entries -> scratch)."""
-        if not self.paged:
-            return
         row = self.tables[slot]
         row[:] = self.scratch_block
         ids = self.kv.table_ids(slot)
@@ -1763,8 +1710,7 @@ class ContinuousEngine:
         self.slots[slot] = None
         self._slot_prompt[slot] = None
         self.slot_phase[slot] = FREE
-        if self.paged:
-            self.tables[slot, :] = self.scratch_block
+        self.tables[slot, :] = self.scratch_block
 
     def _resolve(self, seq: "_Seq", status: str,
                  reason: "str | None" = None) -> None:
@@ -1954,7 +1900,6 @@ class ContinuousEngine:
             f"non-FREE slot phases: {self.slot_phase.tolist()}"
         assert not self.waiting, \
             f"requests still waiting: {[s.req.id for s in self.waiting]}"
-        if self.paged:
-            assert (self.tables == self.scratch_block).all(), \
-                "block-table rows not parked on the scratch block"
+        assert (self.tables == self.scratch_block).all(), \
+            "block-table rows not parked on the scratch block"
         self.kv.assert_quiescent()

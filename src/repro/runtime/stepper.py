@@ -1,6 +1,8 @@
 """Pre-traced batched step functions shared by both serving engines.
 
-One :class:`Stepper` owns exactly two jitted callables per batch shape:
+One :class:`Stepper` owns one jitted program per kind, each over the
+physically paged KV pool and its block table, plus the reset of new
+tenants' per-row state:
 
 * ``decode`` — ONE decode iteration over a whole slot table: every row
   advances from its own ``cache_len`` with an ``active`` validity mask,
@@ -39,8 +41,9 @@ zero extra dispatches.  Fault injection uses separately-jitted
 executables never contain injection code.
 
 Trace counters are incremented inside the traced Python bodies (which
-run only at trace time), so ``chunk_traces`` / ``decode_traces`` observe
-XLA retraces directly; ``dispatches`` counts calls.
+run only at trace time), so ``chunk_traces`` / ``decode_traces`` /
+``megastep_traces`` observe XLA retraces directly; ``dispatches``
+counts calls.
 
 The programs keep fixed names (:data:`DECODE_PROGRAM`,
 :data:`MEGASTEP_PROGRAM`, :data:`CHUNK_PROGRAM`, :data:`RESET_PROGRAM`),
@@ -88,8 +91,7 @@ def _device(x, dtype):
 class Stepper:
     """Batched, validity-masked decode/prefill dispatches for one model.
 
-    Each step function exists in a *dense* and a *paged* flavor: the
-    paged twins additionally take a ``(B, blocks_per_seq)`` block table
+    Every step function takes a ``(B, blocks_per_seq)`` block table
     routing every attention layer's physical block pool (see
     ``models.attention.init_paged_kv_cache``).  The table is a traced
     argument — its *values* change every iteration as blocks are
@@ -107,12 +109,7 @@ class Stepper:
         self.metrics = m
         self._m_chunk_traces = m.counter("stepper.chunk_traces")
         self._m_decode_traces = m.counter("stepper.decode_traces")
-        self._m_paged_chunk_traces = m.counter("stepper.paged_chunk_traces")
-        self._m_paged_decode_traces = \
-            m.counter("stepper.paged_decode_traces")
         self._m_megastep_traces = m.counter("stepper.megastep_traces")
-        self._m_paged_megastep_traces = \
-            m.counter("stepper.paged_megastep_traces")
         # fault-injection twins trace separately (chaos-only): counted
         # apart so the clean counters' no-retrace assertions stay exact
         self._m_poisoned_traces = m.counter("stepper.poisoned_traces")
@@ -124,22 +121,13 @@ class Stepper:
         #: the encoder-decoder's decode step, consume one token
         self.block_chunk = not self.cfg.is_encoder_decoder and all(
             mixer == "attn" for mixer, _ in block_pattern(self.cfg))
-        # distinct megastep lengths traced, per flavor: a (flavor, N)
-        # re-appearing would mean a RE-trace (tests assert counters ==
-        # set sizes, i.e. one trace per distinct scan length)
-        self.megastep_sizes: "set[tuple[bool, int]]" = set()
-        self._decode = _program(self._make_decode(paged=False),
-                                DECODE_PROGRAM)
-        self._chunk = _program(self._make_chunk(paged=False),
-                               CHUNK_PROGRAM)
-        self._decode_paged = _program(self._make_decode(paged=True),
-                                      DECODE_PROGRAM)
-        self._chunk_paged = _program(self._make_chunk(paged=True),
-                                     CHUNK_PROGRAM)
-        self._mega = _program(self._make_megastep(paged=False),
-                              MEGASTEP_PROGRAM)
-        self._mega_paged = _program(self._make_megastep(paged=True),
-                                    MEGASTEP_PROGRAM)
+        # distinct megastep lengths traced: an N re-appearing would mean
+        # a RE-trace (tests assert counter == set size, i.e. one trace
+        # per distinct scan length)
+        self.megastep_sizes: "set[int]" = set()
+        self._decode_paged = _program(self._make_decode(), DECODE_PROGRAM)
+        self._chunk_paged = _program(self._make_chunk(), CHUNK_PROGRAM)
+        self._mega_paged = _program(self._make_megastep(), MEGASTEP_PROGRAM)
         # the reset writes the caches in place: its input is the
         # engine's only reference, and a copy of every row's state would
         # sit beside the chunk's and the megastep's own copies
@@ -148,7 +136,7 @@ class Stepper:
         # poisoned twins — identical math plus an in-trace NaN injection
         # (sampling.poison_logits) — are built lazily on the first
         # poisoned dispatch: a clean run never compiles injection code
-        self._poison_jits: "dict[tuple[str, bool], object]" = {}
+        self._poison_jits: "dict[str, object]" = {}
 
     # -- metric façade (legacy attribute names) -----------------------------
 
@@ -161,20 +149,8 @@ class Stepper:
         return self._m_decode_traces.value
 
     @property
-    def paged_chunk_traces(self) -> int:
-        return self._m_paged_chunk_traces.value
-
-    @property
-    def paged_decode_traces(self) -> int:
-        return self._m_paged_decode_traces.value
-
-    @property
     def megastep_traces(self) -> int:
         return self._m_megastep_traces.value
-
-    @property
-    def paged_megastep_traces(self) -> int:
-        return self._m_paged_megastep_traces.value
 
     @property
     def poisoned_traces(self) -> int:
@@ -188,38 +164,31 @@ class Stepper:
         """Counter snapshot + traced megastep lengths — merged into
         ``engine.stats()`` so one snapshot covers the shared stepper."""
         stats = dict(self.metrics.snapshot()["counters"])
-        stats["megastep_sizes"] = sorted(
-            [list(k) for k in self.megastep_sizes])
+        stats["megastep_sizes"] = sorted(self.megastep_sizes)
         return stats
 
-    def _poisoned(self, kind: str, paged: bool):
-        key = (kind, paged)
-        fn = self._poison_jits.get(key)
+    def _poisoned(self, kind: str):
+        fn = self._poison_jits.get(kind)
         if fn is None:
             maker, name = {"decode": (self._make_decode, DECODE_PROGRAM),
                            "mega": (self._make_megastep,
                                     MEGASTEP_PROGRAM)}[kind]
-            fn = _program(maker(paged=paged, poisoned=True), name)
-            self._poison_jits[key] = fn
+            fn = _program(maker(poisoned=True), name)
+            self._poison_jits[kind] = fn
         return fn
 
     # -- decode -------------------------------------------------------------
 
-    def _make_decode(self, paged: bool, poisoned: bool = False):
+    def _make_decode(self, poisoned: bool = False):
         decode = self.api.decode_fn
 
-        def step(params, caches, toks, lens, active, tables=None,
-                 poison=None):
+        def step(params, caches, toks, lens, active, tables, poison=None):
             if poisoned:                     # trace-time side effects
                 self._m_poisoned_traces.inc()
-            elif paged:
-                self._m_paged_decode_traces.inc()
             else:
                 self._m_decode_traces.inc()
             batch = {"tokens": toks[:, None], "cache_len": lens,
-                     "active": active}
-            if tables is not None:
-                batch["block_tables"] = tables
+                     "active": active, "block_tables": tables}
             logits, caches = decode(params, caches, batch)
             with jax.named_scope("sample"):
                 if poisoned:
@@ -229,48 +198,36 @@ class Stepper:
 
         return step
 
-    def decode(self, params, caches, toks, lens, active,
-               block_tables=None, poison=None):
-        """toks/lens/active (B,) -> (next_tok (B,), bad (B,), caches).
-        ``bad`` flags active rows whose logits came back non-finite (the
-        in-dispatch watchdog — :func:`~repro.runtime.sampling.
-        logits_watchdog`).  ``block_tables`` (B, blocks_per_seq) selects
-        the paged twin; ``poison`` (B,) bool routes to the lazily-built
-        poisoned twin that NaNs those rows' logits in-trace (fault
-        injection — never compiled on clean runs)."""
+    def decode(self, params, caches, toks, lens, active, block_tables,
+               poison=None):
+        """toks/lens/active (B,), block_tables (B, blocks_per_seq) ->
+        (next_tok (B,), bad (B,), caches).  ``bad`` flags active rows
+        whose logits came back non-finite (the in-dispatch watchdog —
+        :func:`~repro.runtime.sampling.logits_watchdog`).  ``poison``
+        (B,) bool routes to the lazily-built poisoned twin that NaNs
+        those rows' logits in-trace (fault injection — never compiled on
+        clean runs)."""
         self._m_dispatches.inc()
         args = (params, caches, _device(toks, jnp.int32),
-                _device(lens, jnp.int32), _device(active, bool))
+                _device(lens, jnp.int32), _device(active, bool),
+                _device(block_tables, jnp.int32))
         if poison is not None:
-            fn = self._poisoned("decode", block_tables is not None)
-            tbl = None if block_tables is None \
-                else _device(block_tables, jnp.int32)
-            return fn(*args, tbl, _device(poison, bool))
-        if block_tables is None:
-            return self._decode(*args)
-        return self._decode_paged(*args,
-                                  _device(block_tables, jnp.int32))
+            return self._poisoned("decode")(*args, _device(poison, bool))
+        return self._decode_paged(*args)
 
     # -- chunked prefill ----------------------------------------------------
 
-    def _make_chunk(self, paged: bool):
+    def _make_chunk(self):
         decode = self.api.decode_fn
 
-        def count_trace():                   # trace-time side effects
-            if paged:
-                self._m_paged_chunk_traces.inc()
-            else:
-                self._m_chunk_traces.inc()
-
-        def run_block(params, caches, toks, lens, n_valid, tables=None):
-            count_trace()
+        def run_block(params, caches, toks, lens, n_valid, tables):
+            self._m_chunk_traces.inc()       # trace-time side effect
             C = toks.shape[1]
             batch = {"tokens": toks, "cache_len": lens,
                      "active": jnp.arange(C, dtype=jnp.int32)
                      < n_valid[:, None],
-                     "logits_at": jnp.maximum(n_valid - 1, 0)}
-            if tables is not None:
-                batch["block_tables"] = tables
+                     "logits_at": jnp.maximum(n_valid - 1, 0),
+                     "block_tables": tables}
             logits, caches = decode(params, caches, batch)
             with jax.named_scope("sample"):
                 started = n_valid > 0
@@ -281,8 +238,8 @@ class Stepper:
         if self.block_chunk:
             return run_block
 
-        def run_chunk(params, caches, toks, lens, n_valid, tables=None):
-            count_trace()
+        def run_chunk(params, caches, toks, lens, n_valid, tables):
+            self._m_chunk_traces.inc()       # trace-time side effect
             B, C = toks.shape
 
             def step(carry, x):
@@ -290,9 +247,7 @@ class Stepper:
                 tok_col, i = x
                 active = i < n_valid
                 batch = {"tokens": tok_col[:, None], "cache_len": lens,
-                         "active": active}
-                if tables is not None:
-                    batch["block_tables"] = tables
+                         "active": active, "block_tables": tables}
                 logits, caches = decode(params, caches, batch)
                 with jax.named_scope("sample"):
                     first = jnp.where(i == n_valid - 1,
@@ -311,40 +266,33 @@ class Stepper:
         return run_chunk
 
     def prefill_chunk(self, params, caches, toks, lens, n_valid,
-                      block_tables=None):
-        """toks (B, C); lens/n_valid (B,).  Consumes ``n_valid[b]`` prompt
-        tokens for row b starting at its ``lens[b]`` cache position.
-        Returns (caches, new lens, first-token per row — meaningful only
-        for rows whose prompt completed inside this chunk, watchdog flag
-        per row OR-ed over the chunk's tokens)."""
+                      block_tables):
+        """toks (B, C); lens/n_valid (B,); block_tables (B,
+        blocks_per_seq).  Consumes ``n_valid[b]`` prompt tokens for row b
+        starting at its ``lens[b]`` cache position.  Returns (caches, new
+        lens, first-token per row — meaningful only for rows whose prompt
+        completed inside this chunk, watchdog flag per row OR-ed over the
+        chunk's tokens)."""
         self._m_dispatches.inc()
         if self.block_chunk:
             self._m_block_chunks.inc()
-        if block_tables is None:
-            return self._chunk(params, caches, _device(toks, jnp.int32),
-                               _device(lens, jnp.int32),
-                               _device(n_valid, jnp.int32))
-        return self._chunk_paged(params, caches,
-                                 _device(toks, jnp.int32),
+        return self._chunk_paged(params, caches, _device(toks, jnp.int32),
                                  _device(lens, jnp.int32),
                                  _device(n_valid, jnp.int32),
                                  _device(block_tables, jnp.int32))
 
     # -- decode megastep ----------------------------------------------------
 
-    def _make_megastep(self, paged: bool, poisoned: bool = False):
+    def _make_megastep(self, poisoned: bool = False):
         decode = self.api.decode_fn
 
         def run(params, caches, toks, lens, active, budget, forced,
-                n_forced, eos_ids, tables=None, poison=None):
+                n_forced, eos_ids, tables, poison=None):
             if poisoned:                     # trace-time side effects
                 self._m_poisoned_traces.inc()
             else:
-                if paged:
-                    self._m_paged_megastep_traces.inc()
-                else:
-                    self._m_megastep_traces.inc()
-                self.megastep_sizes.add((paged, forced.shape[1]))
+                self._m_megastep_traces.inc()
+                self.megastep_sizes.add(forced.shape[1])
             N = forced.shape[1]
 
             def body(carry, xs):
@@ -355,9 +303,7 @@ class Stepper:
                 # else feeds back the sampled carry
                 tok_in = jnp.where(s < n_forced, f_col, last)
                 batch = {"tokens": tok_in[:, None], "cache_len": lens,
-                         "active": active}
-                if tables is not None:
-                    batch["block_tables"] = tables
+                         "active": active, "block_tables": tables}
                 logits, caches = decode(params, caches, batch)
                 with jax.named_scope("sample"):
                     if poisoned:
@@ -385,35 +331,31 @@ class Stepper:
         return run
 
     def megastep(self, params, caches, toks, lens, active, budget,
-                 forced, n_forced, eos_ids, block_tables=None,
-                 poison=None):
+                 forced, n_forced, eos_ids, block_tables, poison=None):
         """N fused decode iterations, ONE dispatch, ONE host sync.
 
         toks/lens/active/budget/n_forced/eos_ids (B,); forced (B, N)
         prompt tokens to force-feed (row b uses column s while
-        ``s < n_forced[b]``).  Returns ``(toks_out (N, B), act_out
-        (N, B), bad (B,), new caches)`` — ``act_out[s]`` is the mask of
-        rows that executed scan step ``s``; the token stream of row b is
-        ``toks_out[n_forced[b]-1 : steps_taken, b]``; ``bad`` is the
-        in-carry NaN watchdog, OR-ed over every executed step.  The
-        caller must have reserved cache blocks for every position the
-        scan can write: the scan itself never allocates.  ``poison``
-        (B,) bool routes to the lazily-built poisoned twin (fault
-        injection at scan step 0; never compiled on clean runs).
+        ``s < n_forced[b]``); block_tables (B, blocks_per_seq).  Returns
+        ``(toks_out (N, B), act_out (N, B), bad (B,), new caches)`` —
+        ``act_out[s]`` is the mask of rows that executed scan step
+        ``s``; the token stream of row b is ``toks_out[n_forced[b]-1 :
+        steps_taken, b]``; ``bad`` is the in-carry NaN watchdog, OR-ed
+        over every executed step.  The caller must have reserved cache
+        blocks for every position the scan can write: the scan itself
+        never allocates.  ``poison`` (B,) bool routes to the lazily-built
+        poisoned twin (fault injection at scan step 0; never compiled on
+        clean runs).
         """
         self._m_dispatches.inc()
         args = (params, caches, _device(toks, jnp.int32),
                 _device(lens, jnp.int32), _device(active, bool),
                 _device(budget, jnp.int32), _device(forced, jnp.int32),
-                _device(n_forced, jnp.int32), _device(eos_ids, jnp.int32))
+                _device(n_forced, jnp.int32), _device(eos_ids, jnp.int32),
+                _device(block_tables, jnp.int32))
         if poison is not None:
-            fn = self._poisoned("mega", block_tables is not None)
-            tbl = None if block_tables is None \
-                else _device(block_tables, jnp.int32)
-            return fn(*args, tbl, _device(poison, bool))
-        if block_tables is None:
-            return self._mega(*args)
-        return self._mega_paged(*args, _device(block_tables, jnp.int32))
+            return self._poisoned("mega")(*args, _device(poison, bool))
+        return self._mega_paged(*args)
 
     # -- slot reset ---------------------------------------------------------
 
@@ -422,9 +364,6 @@ class Stepper:
             def clear(cache, batch_axis):
                 out = {}
                 for name, a in cache.items():
-                    if name == "pos":        # shared slot index, rowless
-                        out[name] = a
-                        continue
                     if name in ("k_pool", "v_pool"):
                         # physical block pools have no batch axis and
                         # need no reset: every position a new tenant can
@@ -446,7 +385,7 @@ class Stepper:
 
     def reset_rows(self, caches, fresh):
         """Zero every cache entry of rows with ``fresh[b]`` True — a new
-        tenant must see exactly the state `init_caches` would give it
+        tenant must see exactly the state `init_paged_caches` would give it
         (SSM state / conv windows are carried outside the masked KV
         region, so stale tenants would otherwise leak through).
         ``caches`` is donated: the caller keeps only what it returns."""

@@ -69,6 +69,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.runtime.config import EngineConfig
 from repro.runtime.engine import (COMPLETION_STATUSES, FREE, PREFILL,
                                   ContinuousEngine, Request,
                                   ServingEngine)
@@ -90,6 +91,13 @@ def mixed_requests(cfg, n=7, seed=0):
             for i in range(n)]
 
 
+def round_engine(api, params, block_size, stepper):
+    """The round engine at the identity matrix's batch and context."""
+    return ServingEngine(api, params, stepper=stepper, config=EngineConfig(
+        hbm_budget=1 << 30, max_batch=MAX_BATCH, max_context=MAX_CONTEXT,
+        block_size=block_size))
+
+
 def run_arch(arch: str) -> dict:
     cfg = get_config(arch).reduced()
     api = build_model(cfg)
@@ -100,32 +108,21 @@ def run_arch(arch: str) -> dict:
     def fresh(r):
         return Request(r.id, r.prompt, r.max_new_tokens, r.eos_id)
 
-    r_eng = ServingEngine(api, params, hbm_budget_bytes=1 << 30,
-                          max_batch=MAX_BATCH, max_context=MAX_CONTEXT,
-                          stepper=shared)
-    # continuous engine on the PHYSICALLY PAGED cache (the default) and
-    # on the dense per-slot baseline: all three must emit the same bits
+    # the round engine on the continuous engine's pool shape (same
+    # max_batch, max_context and block_size): both run the same traces
+    r_eng = round_engine(api, params, BLOCK, shared)
     c_eng = ContinuousEngine(api, params, hbm_budget_bytes=1 << 30,
                              max_batch=MAX_BATCH, block_size=BLOCK,
                              max_context=MAX_CONTEXT, stepper=shared)
-    d_eng = ContinuousEngine(api, params, hbm_budget_bytes=1 << 30,
-                             max_batch=MAX_BATCH, block_size=BLOCK,
-                             max_context=MAX_CONTEXT, stepper=shared,
-                             paged=False)
     for r in reqs:
         r_eng.submit(fresh(r))
         c_eng.submit(fresh(r))
-        d_eng.submit(fresh(r))
-    rd, cd, dd = r_eng.run(), c_eng.run(), d_eng.run()
+    rd, cd = r_eng.run(), c_eng.run()
     c_eng.assert_quiescent()
-    d_eng.assert_quiescent()
     n_tokens = sum(len(c.tokens) for c in cd.values())
 
     out = {
         "identical": all(rd[r.id].tokens == cd[r.id].tokens for r in reqs),
-        "paged_matches_dense": all(dd[r.id].tokens == cd[r.id].tokens
-                                   for r in reqs),
-        "paged": c_eng.paged,
         "n_tokens": n_tokens,
         "round_dispatches": r_eng.dispatches,
         "cont_dispatches": c_eng.dispatches,
@@ -212,14 +209,13 @@ def run_arch(arch: str) -> dict:
         eos_streams[0][longest.id]
         == stream[:stream.index(eos_tok) + 1])
 
-    # ALL paged engines above share one pool shape: ONE paged decode
-    # trace + ONE paged chunk trace for the whole matrix; the megastep
+    # ALL engines above, round and continuous, share one pool shape: ONE
+    # decode trace + ONE chunk trace for the whole matrix; the megastep
     # traces once per DISTINCT scan length and never re-traces
-    out["single_paged_decode_trace"] = shared.paged_decode_traces == 1
-    out["single_paged_chunk_trace"] = shared.paged_chunk_traces == 1
+    out["single_paged_decode_trace"] = shared.decode_traces == 1
+    out["single_paged_chunk_trace"] = shared.chunk_traces == 1
     out["megastep_no_retrace"] = (
-        shared.megastep_traces + shared.paged_megastep_traces
-        == len(shared.megastep_sizes))
+        shared.megastep_traces == len(shared.megastep_sizes))
 
     # prefix sharing (attention-only archs): staggered lifetimes so
     # later admissions overlap live holders of the same prompt prefix —
@@ -259,29 +255,33 @@ def run_arch(arch: str) -> dict:
         out["sharing_saved_blocks"] = (share_off.kv.acquired_blocks
                                        - share_on.kv.acquired_blocks)
 
-    # paged streams must be invariant to the block size — sweep 1
-    # (token-per-block), 16 (= max_batch boundary) and a non-power-of-
-    # two; each size is a new pool shape, so each sweep engine brings
-    # its own stepper (shape change retraces regardless)
+    # streams must be invariant to the block size — sweep 1 (token-per-
+    # block), a non-power-of-two and 16 (two blocks a row), each on both
+    # engines: the round engine's contiguous table must give the
+    # continuous engine's streams.  Each size is a new pool shape, so
+    # each size's pair of engines brings its own stepper.
     if out["has_attn"]:
         sweeps = []
-        for bsz in (1, 5, 16):
-            eng = ContinuousEngine(api, params, hbm_budget_bytes=1 << 30,
-                                   max_batch=MAX_BATCH, block_size=bsz,
-                                   max_context=MAX_CONTEXT,
-                                   stepper=Stepper(api))
-            for r in reqs:
-                eng.submit(fresh(r))
-            ed = eng.run()
-            eng.assert_quiescent()
-            sweeps.append(all(ed[r.id].tokens == cd[r.id].tokens
-                              for r in reqs))
+        for bsz in (1, 3, 16):
+            step_b = Stepper(api)
+            for eng in (ContinuousEngine(api, params,
+                                         hbm_budget_bytes=1 << 30,
+                                         max_batch=MAX_BATCH,
+                                         block_size=bsz,
+                                         max_context=MAX_CONTEXT,
+                                         stepper=step_b),
+                        round_engine(api, params, bsz, step_b)):
+                for r in reqs:
+                    eng.submit(fresh(r))
+                ed = eng.run()
+                if isinstance(eng, ContinuousEngine):
+                    eng.assert_quiescent()
+                sweeps.append(all(ed[r.id].tokens == cd[r.id].tokens
+                                  for r in reqs))
         out["block_size_invariant"] = all(sweeps)
 
     # greedy decode must be deterministic across engine instances
-    again = ServingEngine(api, params, hbm_budget_bytes=1 << 30,
-                          max_batch=MAX_BATCH, max_context=MAX_CONTEXT,
-                          stepper=shared)
+    again = round_engine(api, params, BLOCK, shared)
     for r in reqs:
         again.submit(fresh(r))
     ad = again.run()
@@ -683,8 +683,6 @@ def run_cache(arch: str) -> dict:
     * a tight-budget sequential run over UNIQUE prompts: the cache
       overflows and LRU-evicts mid-workload; streams stay identical
     """
-    from repro.runtime.config import EngineConfig
-
     cfg = get_config(arch).reduced()
     api = build_model(cfg)
     params = api.init(jax.random.key(0))

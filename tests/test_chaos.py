@@ -380,19 +380,23 @@ def test_stall_iterations_are_visible(model):
 
 
 def test_host_pool_env_knob(monkeypatch):
-    from repro.runtime.engine import HOST_POOL_ENV, host_pool_from_env
+    from repro.runtime.config import HOST_POOL_ENV, EngineConfig
+
+    def host_pool(**kw):
+        return EngineConfig(hbm_budget=1 << 30, **kw).host_pool
+
     monkeypatch.delenv(HOST_POOL_ENV, raising=False)
-    assert host_pool_from_env() == 0          # unset: tier disabled
-    assert host_pool_from_env(1 << 20) == 1 << 20   # explicit wins
+    assert host_pool() == 0                   # unset: tier disabled
+    assert host_pool(host_pool=1 << 20) == 1 << 20  # explicit wins
     monkeypatch.setenv(HOST_POOL_ENV, "512K")
-    assert host_pool_from_env() == 512 << 10
-    assert host_pool_from_env(0) == 0         # explicit 0 beats env
+    assert host_pool() == 512 << 10
+    assert host_pool(host_pool=0) == 0        # explicit 0 beats env
     monkeypatch.setenv(HOST_POOL_ENV, "lots")
     with pytest.raises(ValueError, match=HOST_POOL_ENV):
-        host_pool_from_env()
+        host_pool()
     monkeypatch.setenv(HOST_POOL_ENV, "-4K")
     with pytest.raises(ValueError, match=">= 0"):
-        host_pool_from_env()
+        host_pool()
 
 
 def test_budget_shrink_without_restore_still_raises(model):

@@ -113,14 +113,14 @@ def test_cli_roundtrip_and_precedence(monkeypatch):
     monkeypatch.setenv(MEGASTEP_ENV, "3")
     monkeypatch.setenv(HOST_POOL_ENV, "256M")
     args = _parse(["--max-batch", "5", "--host-pool", "0",
-                   "--hbm-budget", "128M", "--no-paged",
+                   "--hbm-budget", "128M", "--no-prefix-sharing",
                    "--max-context", "none"])
     c = EngineConfig.from_cli_args(args)
     assert c.max_batch == 5
     assert c.host_pool == 0          # flag beats env
     assert c.megastep == 3           # absent flag falls to env
     assert c.hbm_budget == 128 << 20
-    assert c.paged is False
+    assert c.prefix_sharing is False
     assert c.max_context is None
     d = EngineConfig.from_cli_args(args, max_batch=9)
     assert d.max_batch == 9          # overrides beat flags
@@ -131,7 +131,7 @@ def test_cli_help_documents_env_and_default():
     EngineConfig.add_cli_args(ap)
     text = ap.format_help()
     assert MEGASTEP_ENV in text and HOST_POOL_ENV in text
-    assert "--megastep" in text and "--no-paged" in text
+    assert "--megastep" in text and "--no-prefix-sharing" in text
 
 
 # -- deprecated kwarg shim (constructor-level, no model) ---------------------

@@ -20,8 +20,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import build_model
-from repro.runtime.engine import (ContinuousEngine, Request,
-                                  megastep_from_env)
+from repro.runtime.config import EngineConfig
+from repro.runtime.engine import ContinuousEngine, Request
 from repro.runtime.kv_cache import BlockKVCache
 
 CHILD = os.path.join(os.path.dirname(__file__),
@@ -167,20 +167,23 @@ def test_release_to_property_reserve_release_roundtrip():
 # -- knob resolution ---------------------------------------------------------
 
 def test_megastep_env_knob(monkeypatch):
+    def megastep(**kw):
+        return EngineConfig(hbm_budget=1 << 30, **kw).megastep
+
     monkeypatch.delenv("PARALLAX_MEGASTEP", raising=False)
-    assert megastep_from_env() == 8               # default: on, safe N
-    assert megastep_from_env(3) == 3              # explicit wins
+    assert megastep() == 8                        # default: on, safe N
+    assert megastep(megastep=3) == 3              # explicit wins
     monkeypatch.setenv("PARALLAX_MEGASTEP", "4")
-    assert megastep_from_env() == 4
-    assert megastep_from_env(2) == 2              # explicit beats env
+    assert megastep() == 4
+    assert megastep(megastep=2) == 2              # explicit beats env
     monkeypatch.setenv("PARALLAX_MEGASTEP", "1")
-    assert megastep_from_env() == 1               # per-iteration path
+    assert megastep() == 1                        # per-iteration path
     monkeypatch.setenv("PARALLAX_MEGASTEP", "zero")
     with pytest.raises(ValueError, match="PARALLAX_MEGASTEP"):
-        megastep_from_env()
+        megastep()
     monkeypatch.setenv("PARALLAX_MEGASTEP", "0")
     with pytest.raises(ValueError, match=">= 1"):
-        megastep_from_env()
+        megastep()
 
 
 def test_megastep_one_never_fuses():

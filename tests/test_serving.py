@@ -47,18 +47,18 @@ def test_continuous_streams_bit_identical_to_round(identity_report):
     benchmarks/serving.py — tiny identity workloads can tie.)"""
     for arch in IDENTITY_ARCHS:
         r = identity_report[arch]
-        assert r["paged"], f"{arch}: continuous engine not on paged cache"
         assert r["identical"], f"{arch}: streams diverged"
         assert r["n_tokens"] > 0
 
 
 def test_paged_cache_bit_identical_to_dense(identity_report):
-    """The physically paged cache is a pure memory-layout change: the
-    continuous engine must emit the same bits on paged and dense caches,
-    for every block size in the matrix (1, non-power-of-two, 16)."""
+    """The block size is a pure memory-layout choice: the continuous
+    engine must emit the same bits for every block size in the matrix
+    (1, non-power-of-two, 16), and the round engine on its contiguous
+    table the same bits as the continuous engine, at each size."""
     for arch in IDENTITY_ARCHS:
         r = identity_report[arch]
-        assert r["paged_matches_dense"], f"{arch}: paged != dense"
+        assert r["identical"], f"{arch}: round != continuous"
         if r["has_attn"]:
             assert r["block_size_invariant"], \
                 f"{arch}: block size changed decoded tokens"
@@ -126,9 +126,10 @@ def test_prefix_cache_concurrent_and_eviction_identity(cache_report):
 
 
 def test_single_paged_trace_across_engines(identity_report):
-    """Every paged engine with one pool shape — including preempting,
-    tight-budget and sharing engines — reuses ONE compiled paged decode
-    + chunk trace (block tables are traced values, not shapes)."""
+    """Every engine with one pool shape — the round engine and the
+    preempting, tight-budget and sharing continuous engines — reuses ONE
+    compiled decode + chunk trace (block tables are traced values, not
+    shapes)."""
     for arch in IDENTITY_ARCHS:
         assert identity_report[arch]["single_paged_decode_trace"], arch
         assert identity_report[arch]["single_paged_chunk_trace"], arch
@@ -167,7 +168,8 @@ def test_greedy_decode_deterministic_and_chunk_invariant(identity_report):
 def test_single_trace_per_step_fn(identity_report):
     """The whole run — mixed prompt lengths, ragged final chunks,
     requests joining/leaving — compiles ONE decode trace and ONE chunk
-    trace (the shared stepper served five engines per arch)."""
+    trace (the shared stepper served the round and the continuous
+    engine on one pool shape)."""
     for arch in IDENTITY_ARCHS:
         assert identity_report[arch]["single_decode_trace"], arch
         assert identity_report[arch]["single_chunk_trace"], arch
@@ -196,8 +198,8 @@ def test_megastep_eos_terminates_in_carry(identity_report):
 
 def test_megastep_traces_once_per_scan_length(identity_report):
     """Each distinct megastep length compiles exactly once; re-tracing
-    an already-seen (flavor, N) would mean the scan signature leaks
-    per-iteration values."""
+    an already-seen N would mean the scan signature leaks per-iteration
+    values."""
     for arch in IDENTITY_ARCHS:
         assert identity_report[arch]["megastep_no_retrace"], arch
 
